@@ -15,7 +15,7 @@ from benchmarks.common import emit
 from repro.configs.base import ShapeConfig
 from repro.configs.registry import get_config
 from repro.data.pipeline import TokenDataset, TruffleDataLoader
-from repro.launch.mesh import host_device_mesh, set_mesh
+from repro.launch.mesh import host_device_mesh
 from repro.launch.steps import build_train_step, concrete_train_state
 from repro.distributed.sharding import rules_for_shape
 from repro.runtime.clock import Clock
@@ -41,7 +41,7 @@ def _one_run(overlap: bool, *, provision_s: float = 1.0) -> float:
 
     def cold():
         clock.sleep(provision_s)                       # ν (simulated)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             box["exe"] = jax.jit(train_step).lower(state_sds, batch_sds).compile()
 
     if overlap:                                        # Truffle path
@@ -53,7 +53,7 @@ def _one_run(overlap: bool, *, provision_s: float = 1.0) -> float:
         cold()
         loader.start_prefetch()
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = concrete_train_state(cfg, mesh, rules_for_shape("train"),
                                      jax.random.PRNGKey(0))
     batch = {k: jnp.asarray(v) for k, v in loader.get(0).items()}

@@ -97,16 +97,6 @@ def chunk_codec(name: Optional[str]) -> Optional[ChunkCodec]:
                        f"(have: {sorted(_CHUNK_CODECS)})") from None
 
 
-def _axis_size(axis_name: str) -> int:
-    """jax.lax.axis_size (jax >= 0.6) with the 0.4.x psum(1) idiom as
-    fallback (statically concretized under shard_map/pmap tracing)."""
-    import jax
-    impl = getattr(jax.lax, "axis_size", None)
-    if impl is not None:
-        return impl(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
 def quantize(x: jax.Array, bits: int = 8) -> Tuple[jax.Array, jax.Array]:
     """Symmetric per-tensor quantization -> (int8 codes, fp32 scale)."""
     import jax.numpy as jnp
@@ -137,7 +127,7 @@ def compressed_mean(x: jax.Array, axis_name: str) -> jax.Array:
     and gather codes+scales."""
     import jax
     import jax.numpy as jnp
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if n == 1:
         return x
     size = x.size

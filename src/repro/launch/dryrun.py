@@ -25,7 +25,7 @@ from repro.configs.base import SHAPES, shape_applicable
 from repro.configs.registry import get_config, list_archs
 from repro.launch import hlo
 from repro.launch.flops import model_flops
-from repro.launch.mesh import make_production_mesh, set_mesh
+from repro.launch.mesh import make_production_mesh
 from repro.launch.steps import build_step
 
 OUT_DIR = Path(__file__).resolve().parents[3] / "experiments" / "dryrun"
@@ -34,7 +34,7 @@ OUT_DIR = Path(__file__).resolve().parents[3] / "experiments" / "dryrun"
 def _compile_cell(cfg, shape, mesh, multi_pod, step_kw, jit_kw=None):
     fn, abstract_args = build_step(cfg, mesh, shape, multi_pod=multi_pod, **step_kw)
     t0 = time.time()
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(fn, **(jit_kw or {})).lower(*abstract_args)
         t1 = time.time()
         compiled = lowered.compile()

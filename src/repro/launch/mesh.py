@@ -11,17 +11,9 @@ def make_production_mesh(*, multi_pod: bool = False):
     return make_mesh(shape, axes)
 
 
-def set_mesh(mesh):
-    """Context manager activating ``mesh``: jax.set_mesh on jax >= 0.6;
-    on 0.4.x the Mesh object is itself the context manager."""
-    impl = getattr(jax, "set_mesh", None)
-    return impl(mesh) if impl is not None else mesh
-
-
 def make_mesh(shape, axes):
-    axis_type = getattr(jax.sharding, "AxisType", None)  # jax >= 0.6 only
-    kw = {"axis_types": (axis_type.Auto,) * len(axes)} if axis_type else {}
-    return jax.make_mesh(tuple(shape), tuple(axes), **kw)
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def host_device_mesh(n_data: int = 1, n_model: int = 1):

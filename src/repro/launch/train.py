@@ -32,7 +32,8 @@ from repro.configs.registry import get_config
 from repro.core.buffer import Buffer
 from repro.data.pipeline import TokenDataset, TruffleDataLoader
 from repro.distributed.sharding import rules_for_shape
-from repro.launch.mesh import host_device_mesh, set_mesh
+from repro.launch.compile_cache import use_compilation_cache
+from repro.launch.mesh import host_device_mesh
 from repro.launch.steps import build_train_step, concrete_train_state
 from repro.optim.adamw import OptConfig
 from repro.runtime.clock import Clock
@@ -66,7 +67,7 @@ def run_incarnation(args, incarnation: int, clock: Clock) -> dict:
 
     def cold_start():  # η: the real XLA compile
         clock.sleep(args.provision_s)  # ν: worker provisioning (simulated)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             compiled_box["exe"] = jax.jit(train_step).lower(
                 state_sds, batch_sds).compile()
 
@@ -90,7 +91,7 @@ def run_incarnation(args, incarnation: int, clock: Clock) -> dict:
         loader.start_prefetch()
 
     exe = compiled_box["exe"]
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = concrete_train_state(cfg, mesh, rules_for_shape("train"),
                                      jax.random.PRNGKey(args.seed))
         start_step = 0
@@ -144,6 +145,7 @@ def main(argv=None):
     ap.add_argument("--time-scale", type=float, default=1.0)
     args = ap.parse_args(argv)
 
+    use_compilation_cache()
     clock = Clock(args.time_scale)
     incarnation = 0
     while True:

@@ -9,6 +9,7 @@ derive:
 """
 from __future__ import annotations
 
+import functools
 import zlib
 from dataclasses import dataclass, replace
 from typing import Any, Dict, Optional, Tuple
@@ -49,6 +50,13 @@ def _path_str(path) -> str:
     return "/".join(str(getattr(k, "key", getattr(k, "idx", k))) for k in path)
 
 
+@functools.partial(jax.jit, static_argnums=(1, 2, 3))
+def _normal(key: jax.Array, shape: Tuple[int, ...], scale: float, dtype: str):
+    """One leaf's f32 draw fused with its cast, so a bf16 leaf never holds a
+    full-size f32 copy on the device."""
+    return (jax.random.normal(key, shape, jnp.float32) * scale).astype(dtype)
+
+
 def init_params(defs: PyTree, key: jax.Array, param_dtype: str = "float32") -> PyTree:
     """Initialize arrays from a def tree (path-stable RNG per leaf)."""
     def init_leaf(path, d: ParamDef):
@@ -58,7 +66,7 @@ def init_params(defs: PyTree, key: jax.Array, param_dtype: str = "float32") -> P
         if d.init == "ones":
             return (jnp.ones(d.shape, jnp.float32) * d.scale).astype(dtype)
         leaf_key = jax.random.fold_in(key, zlib.crc32(_path_str(path).encode()))
-        return (jax.random.normal(leaf_key, d.shape, jnp.float32) * d.scale).astype(dtype)
+        return _normal(leaf_key, d.shape, d.scale, dtype)
     return jax.tree_util.tree_map_with_path(
         init_leaf, defs, is_leaf=lambda x: isinstance(x, ParamDef))
 

@@ -8,7 +8,7 @@ from __future__ import annotations
 import threading
 import time
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional
+from typing import Any, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -24,6 +24,16 @@ class GenRequest:
     prompt: List[int]
     max_new_tokens: int = 8
     result: Optional[List[int]] = None
+
+
+@dataclass
+class DecodeState:
+    """Where a batch's decode stopped: the grown KV cache (Truffle's CSP
+    payload in a prefill->decode handoff), the next input token [B, 1] and
+    the cache slot it is written to."""
+    cache: Any
+    token: jax.Array
+    pos: int
 
 
 @dataclass
@@ -47,7 +57,8 @@ class ServeEngine:
         self._queue: List[GenRequest] = []
         self._lock = threading.Lock()
         self.stats = EngineStats()
-        self._compiled = False
+        self.prompt_len: Optional[int] = None   # prefill length compiled for
+        self.last_state: Optional[DecodeState] = None
 
     # ------------------------------------------------------------- lifecycle
     def warmup(self, prompt_len: int) -> None:
@@ -56,11 +67,15 @@ class ServeEngine:
         t0 = time.monotonic()
         cfg = self.cfg
         B, L = self.max_batch, prompt_len
-        self._prefill = jax.jit(
-            lambda p, b: api.prefill(cfg, p, b)).lower(
-                jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
-                             self.params),
-                {"tokens": jax.ShapeDtypeStruct((B, L), jnp.int32)}).compile()
+
+        def prefill(p, b):              # cache comes out grown to max_len
+            logits, cache = api.prefill(cfg, p, b)
+            return logits, self._grow_cache(cache, L)
+
+        self._prefill = jax.jit(prefill).lower(
+            jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
+                         self.params),
+            {"tokens": jax.ShapeDtypeStruct((B, L), jnp.int32)}).compile()
         cache_sds = api.cache_sds(cfg, B, self.max_len)
         self._decode = jax.jit(
             lambda p, c, t, q: api.decode_step(cfg, p, c, t, q)).lower(
@@ -70,7 +85,7 @@ class ServeEngine:
                 jax.ShapeDtypeStruct((B, 1), jnp.int32),
                 jax.ShapeDtypeStruct((), jnp.int32)).compile()
         self.stats.compile_s = time.monotonic() - t0
-        self._compiled = True
+        self.prompt_len = prompt_len
 
     # --------------------------------------------------------------- serving
     def submit(self, req: GenRequest) -> None:
@@ -89,12 +104,15 @@ class ServeEngine:
         toks = np.zeros((B, plen), np.int32)
         for i, r in enumerate(batch):
             toks[i, plen - len(r.prompt):] = r.prompt        # left-pad
-        if not self._compiled:
+        if self.prompt_len is None:
             self.warmup(plen)
+        elif plen != self.prompt_len:
+            raise ValueError(f"prefill compiled for prompt length "
+                             f"{self.prompt_len}, batch pads to {plen}")
 
         t0 = time.monotonic()
-        logits, cache = self._prefill(self.params, {"tokens": jnp.asarray(toks)})
-        cache = self._grow_cache(cache, plen)
+        logits, cache = jax.block_until_ready(
+            self._prefill(self.params, {"tokens": jnp.asarray(toks)}))
         self.stats.prefill_s += time.monotonic() - t0
 
         t0 = time.monotonic()
@@ -104,19 +122,25 @@ class ServeEngine:
         pos = plen
         token = jnp.asarray(out, jnp.int32)
         for _ in range(max_new - 1):
-            logits, cache = self._decode(self.params, cache, token,
-                                         jnp.asarray(pos, jnp.int32))
+            logits, cache = self.decode(cache, token, pos)
             nxt = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)
             token = nxt[:, None]
             results.append(np.asarray(nxt).tolist())
             pos += 1
         self.stats.decode_s += time.monotonic() - t0
+        self.last_state = DecodeState(cache, token, pos)
 
         gen = np.asarray(results).T                           # [B, max_new]
         for i, r in enumerate(batch):
             r.result = gen[i, :r.max_new_tokens].tolist()
             self.stats.tokens_out += len(r.result)
         return batch
+
+    def decode(self, cache, token: jax.Array, pos: int):
+        """One compiled decode step: token [B, 1] written at cache slot
+        ``pos`` -> (logits [B, 1, V], grown cache)."""
+        return self._decode(self.params, cache, token,
+                            jnp.asarray(pos, jnp.int32))
 
     def _grow_cache(self, cache, plen: int):
         """Pad prefill cache out to max_len decode slots."""

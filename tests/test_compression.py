@@ -58,22 +58,14 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.distributed.compression import compressed_grad_sync
 
-# version-adaptive: jax >= 0.6 has jax.shard_map/check_vma and explicit
-# axis types; 0.4.x uses jax.experimental.shard_map and check_rep
-if hasattr(jax, "shard_map"):
-    shard_map, check_kw = jax.shard_map, {"check_vma": False}
-else:
-    from jax.experimental.shard_map import shard_map
-    check_kw = {"check_rep": False}
-axis_type = getattr(jax.sharding, "AxisType", None)
-mesh_kw = {"axis_types": (axis_type.Explicit,)} if axis_type else {}
-mesh = jax.make_mesh((8,), ("data",), **mesh_kw)
+mesh = jax.make_mesh((8,), ("data",),
+                     axis_types=(jax.sharding.AxisType.Explicit,))
 g = {"w": jax.random.normal(jax.random.PRNGKey(0), (8, 64, 513)),
      "b": jax.random.normal(jax.random.PRNGKey(1), (8, 33))}
 
-@functools.partial(shard_map, mesh=mesh,
+@functools.partial(jax.shard_map, mesh=mesh,
                    in_specs=({"w": P("data"), "b": P("data")},),
-                   out_specs={"w": P(), "b": P()}, **check_kw)
+                   out_specs={"w": P(), "b": P()}, check_vma=False)
 def sync(tree):
     local = jax.tree.map(lambda x: x[0], tree)
     return compressed_grad_sync(local, "data")

@@ -11,16 +11,12 @@ from jax.sharding import PartitionSpec as P
 from repro.distributed.sharding import (default_rules, rules_for_shape,
                                         spec_for_axes)
 from repro.distributed.zero import zero1_spec
-from repro.launch.mesh import make_mesh
 
 
 @pytest.fixture(scope="module")
 def mesh():
     # 1 real device; abstract mesh construction needs none
-    try:
-        return jax.sharding.AbstractMesh((16, 16), ("data", "model"))
-    except TypeError:   # jax 0.4.x signature: ((name, size), ...) pairs
-        return jax.sharding.AbstractMesh((("data", 16), ("model", 16)))
+    return jax.sharding.AbstractMesh((16, 16), ("data", "model"))
 
 
 def test_spec_basic(mesh):

@@ -10,8 +10,15 @@ from repro.models import api
 from repro.serving.engine import GenRequest, ServeEngine
 
 
+def _private_compile_cache(monkeypatch, tmp_path):
+    """train.main picks the compile cache; with the variable set it leaves
+    this worker's JAX config (and the checkout's cache directory) alone."""
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "jax_cache"))
+
+
 @pytest.mark.slow
-def test_train_failure_restart_resume(tmp_path):
+def test_train_failure_restart_resume(tmp_path, monkeypatch):
+    _private_compile_cache(monkeypatch, tmp_path)
     out = train.main([
         "--arch", "qwen3-4b", "--steps", "8", "--batch", "2", "--seq", "32",
         "--ckpt-every", "3", "--inject-failure", "4",
@@ -24,7 +31,8 @@ def test_train_failure_restart_resume(tmp_path):
 
 
 @pytest.mark.slow
-def test_train_loss_decreases(tmp_path):
+def test_train_loss_decreases(tmp_path, monkeypatch):
+    _private_compile_cache(monkeypatch, tmp_path)
     out = train.main([
         "--arch", "xlstm-125m", "--steps", "15", "--batch", "4", "--seq", "32",
         "--ckpt-dir", str(tmp_path), "--log-every", "100", "--lr", "3e-3",
@@ -58,3 +66,13 @@ def test_serving_engine_greedy_deterministic():
         eng.submit(GenRequest("r", [1, 2, 3, 4, 5, 6, 7, 8], max_new_tokens=4))
         outs.append(eng.step_batch()[0].result)
     assert outs[0] == outs[1]
+
+
+def test_serving_engine_rejects_prompt_length_it_was_not_compiled_for():
+    cfg = get_config("qwen3-4b", smoke=True)
+    params = api.init(cfg, jax.random.PRNGKey(0))
+    eng = ServeEngine(cfg, params, max_batch=1, max_len=16)
+    eng.warmup(8)
+    eng.submit(GenRequest("r", [1, 2, 3, 4], max_new_tokens=4))
+    with pytest.raises(ValueError, match="prompt length 8"):
+        eng.step_batch()
