@@ -32,9 +32,11 @@ def _flatten(tree: PyTree) -> Dict[str, np.ndarray]:
     flat = {}
     for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
         key = _SEP.join(str(getattr(k, "key", getattr(k, "idx", k))) for k in path)
-        v = np.asarray(leaf)
+        with jax.profiler.TraceAnnotation("truffle.csp.d2h"):
+            v = np.asarray(leaf)
         if str(v.dtype) not in _NPZ_SAVABLE:   # bf16 etc. -> widen for npz
-            v = v.astype(np.float32)
+            with jax.profiler.TraceAnnotation("truffle.csp.widen"):
+                v = v.astype(np.float32)
         flat[key] = v
     return flat
 
@@ -46,22 +48,35 @@ def _unflatten_into(like: PyTree, flat: Dict[str, np.ndarray]) -> PyTree:
         key = _SEP.join(str(getattr(k, "key", getattr(k, "idx", k))) for k in path)
         if key not in flat:
             raise KeyError(f"checkpoint missing leaf {key}")
-        leaves.append(flat[key].astype(leaf.dtype) if hasattr(leaf, "dtype")
-                      else flat[key])
+        if hasattr(leaf, "dtype"):
+            with jax.profiler.TraceAnnotation("truffle.csp.narrow"):
+                leaves.append(flat[key].astype(leaf.dtype))
+        else:
+            leaves.append(flat[key])
     return jax.tree_util.tree_unflatten(paths[1], leaves)
 
 
 def serialize(tree: PyTree) -> bytes:
-    """Whole-tree bytes (CSP payloads, storage uploads)."""
-    buf = io.BytesIO()
-    np.savez(buf, **_flatten(tree))
-    return buf.getvalue()
+    """Whole-tree bytes (CSP payloads, storage uploads). Its phases are
+    ``truffle.csp.*`` profiler spans: per leaf ``d2h`` (to host) and
+    ``widen`` (dtypes npz cannot hold, to float32), then ``pack``."""
+    with jax.profiler.TraceAnnotation("truffle.csp.serialize"):
+        flat = _flatten(tree)
+        with jax.profiler.TraceAnnotation("truffle.csp.pack"):
+            buf = io.BytesIO()
+            np.savez(buf, **flat)
+            del flat            # free the widened leaves before the copy out
+            return buf.getvalue()
 
 
 def deserialize(data: bytes, like: PyTree) -> PyTree:
-    with np.load(io.BytesIO(data)) as z:
-        flat = {k: z[k] for k in z.files}
-    return _unflatten_into(like, flat)
+    """The tree ``serialize`` wrote, as host arrays in ``like``'s dtypes:
+    ``truffle.csp.unpack``, then per leaf ``truffle.csp.narrow``."""
+    with jax.profiler.TraceAnnotation("truffle.csp.deserialize"):
+        with jax.profiler.TraceAnnotation("truffle.csp.unpack"):
+            with np.load(io.BytesIO(data)) as z:
+                flat = {k: z[k] for k in z.files}
+        return _unflatten_into(like, flat)
 
 
 class CheckpointManager:

@@ -7,7 +7,8 @@ from __future__ import annotations
 
 import threading
 import time
-from dataclasses import dataclass, field
+from contextlib import contextmanager
+from dataclasses import dataclass
 from typing import Any, List, Optional
 
 import jax
@@ -41,8 +42,25 @@ class EngineStats:
     compile_s: float = 0.0
     prefill_s: float = 0.0
     decode_s: float = 0.0
-    time_to_first_batch: float = 0.0
+    # Host time in decode-loop iterations outside the token sync: the
+    # previous step has finished and the next is not yet enqueued, so the
+    # device mostly idles through it.
+    decode_host_s: float = 0.0
+    decode_steps: int = 0
     tokens_out: int = 0
+
+
+@contextmanager
+def _span(name: str, stats: EngineStats, field: str):
+    """A ``jax.profiler`` host span whose elapsed time is also added to
+    ``stats.<field>``. With no profiler session open the span costs next to
+    nothing, so it is always on."""
+    with jax.profiler.TraceAnnotation(name):
+        t0 = time.monotonic()
+        try:
+            yield
+        finally:
+            setattr(stats, field, getattr(stats, field) + time.monotonic() - t0)
 
 
 class ServeEngine:
@@ -64,7 +82,11 @@ class ServeEngine:
     def warmup(self, prompt_len: int) -> None:
         """Cold start: trace+compile prefill and decode (call under Truffle's
         overlap window)."""
-        t0 = time.monotonic()
+        with _span("truffle.engine.warmup", self.stats, "compile_s"):
+            self._compile(prompt_len)
+        self.prompt_len = prompt_len
+
+    def _compile(self, prompt_len: int) -> None:
         cfg = self.cfg
         B, L = self.max_batch, prompt_len
 
@@ -84,8 +106,6 @@ class ServeEngine:
                 cache_sds,
                 jax.ShapeDtypeStruct((B, 1), jnp.int32),
                 jax.ShapeDtypeStruct((), jnp.int32)).compile()
-        self.stats.compile_s = time.monotonic() - t0
-        self.prompt_len = prompt_len
 
     # --------------------------------------------------------------- serving
     def submit(self, req: GenRequest) -> None:
@@ -93,54 +113,70 @@ class ServeEngine:
             self._queue.append(req)
 
     def step_batch(self) -> List[GenRequest]:
-        """Serve one batch from the queue; returns completed requests."""
-        with self._lock:
-            batch = self._queue[:self.max_batch]
-            self._queue = self._queue[self.max_batch:]
-        if not batch:
-            return []
-        B = self.max_batch
-        plen = max(len(r.prompt) for r in batch)
-        toks = np.zeros((B, plen), np.int32)
-        for i, r in enumerate(batch):
-            toks[i, plen - len(r.prompt):] = r.prompt        # left-pad
-        if self.prompt_len is None:
-            self.warmup(plen)
-        elif plen != self.prompt_len:
-            raise ValueError(f"prefill compiled for prompt length "
-                             f"{self.prompt_len}, batch pads to {plen}")
+        """Serve one batch from the queue; returns completed requests.
 
-        t0 = time.monotonic()
-        logits, cache = jax.block_until_ready(
-            self._prefill(self.params, {"tokens": jnp.asarray(toks)}))
-        self.stats.prefill_s += time.monotonic() - t0
+        The host work is tiled by ``truffle.engine.*`` profiler spans:
+        ``batch`` around the whole call (its own time is the queue pop, the
+        padding and the results), ``prefill``, ``first_token``, then per
+        decode step ``decode``, ``sample`` and ``token_sync``, the one place
+        the loop waits on the device."""
+        with jax.profiler.TraceAnnotation("truffle.engine.batch"):
+            with self._lock:
+                batch = self._queue[:self.max_batch]
+                self._queue = self._queue[self.max_batch:]
+            if not batch:
+                return []
+            B = self.max_batch
+            plen = max(len(r.prompt) for r in batch)
+            toks = np.zeros((B, plen), np.int32)
+            for i, r in enumerate(batch):
+                toks[i, plen - len(r.prompt):] = r.prompt        # left-pad
+            if self.prompt_len is None:
+                self.warmup(plen)
+            elif plen != self.prompt_len:
+                raise ValueError(f"prefill compiled for prompt length "
+                                 f"{self.prompt_len}, batch pads to {plen}")
 
-        t0 = time.monotonic()
-        out = np.asarray(jnp.argmax(logits[:, -1], -1)).reshape(B, 1)
-        results = [out[:, 0].tolist()]
-        max_new = max(r.max_new_tokens for r in batch)
-        pos = plen
-        token = jnp.asarray(out, jnp.int32)
-        for _ in range(max_new - 1):
-            logits, cache = self.decode(cache, token, pos)
-            nxt = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)
-            token = nxt[:, None]
-            results.append(np.asarray(nxt).tolist())
-            pos += 1
-        self.stats.decode_s += time.monotonic() - t0
-        self.last_state = DecodeState(cache, token, pos)
+            with _span("truffle.engine.prefill", self.stats, "prefill_s"):
+                logits, cache = jax.block_until_ready(
+                    self._prefill(self.params, {"tokens": jnp.asarray(toks)}))
 
-        gen = np.asarray(results).T                           # [B, max_new]
-        for i, r in enumerate(batch):
-            r.result = gen[i, :r.max_new_tokens].tolist()
-            self.stats.tokens_out += len(r.result)
-        return batch
+            t0 = time.monotonic()
+            with jax.profiler.TraceAnnotation("truffle.engine.first_token"):
+                out = np.asarray(jnp.argmax(logits[:, -1], -1)).reshape(B, 1)
+                results = [out[:, 0].tolist()]
+                token = jnp.asarray(out, jnp.int32)
+            max_new = max(r.max_new_tokens for r in batch)
+            pos = plen
+            host_s, t_host = 0.0, time.monotonic()
+            for _ in range(max_new - 1):
+                logits, cache = self.decode(cache, token, pos)
+                with jax.profiler.TraceAnnotation("truffle.engine.sample"):
+                    nxt = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)
+                    token = nxt[:, None]
+                t_sync = time.monotonic()
+                host_s += t_sync - t_host
+                with jax.profiler.TraceAnnotation("truffle.engine.token_sync"):
+                    results.append(np.asarray(nxt).tolist())
+                t_host = time.monotonic()
+                pos += 1
+            self.stats.decode_s += time.monotonic() - t0
+            self.stats.decode_host_s += host_s
+            self.stats.decode_steps += max_new - 1
+            self.last_state = DecodeState(cache, token, pos)
+
+            gen = np.asarray(results).T                       # [B, max_new]
+            for i, r in enumerate(batch):
+                r.result = gen[i, :r.max_new_tokens].tolist()
+                self.stats.tokens_out += len(r.result)
+            return batch
 
     def decode(self, cache, token: jax.Array, pos: int):
         """One compiled decode step: token [B, 1] written at cache slot
         ``pos`` -> (logits [B, 1, V], grown cache)."""
-        return self._decode(self.params, cache, token,
-                            jnp.asarray(pos, jnp.int32))
+        with jax.profiler.TraceAnnotation("truffle.engine.decode"):
+            return self._decode(self.params, cache, token,
+                                jnp.asarray(pos, jnp.int32))
 
     def _grow_cache(self, cache, plen: int):
         """Pad prefill cache out to max_len decode slots."""
