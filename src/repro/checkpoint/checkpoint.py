@@ -1,5 +1,6 @@
 """Sharded checkpointing: save/restore pytrees as npz shards + manifest,
-async (background-thread) saves, rotation, and CSP-streamed restore.
+async (background-thread) saves, rotation, and CSP-streamed restore; and the
+CSP payload codec (``serialize``/``deserialize``), a raw typed wire format.
 
 Fault-tolerance contract (exercised by launch/train.py --inject-failure):
   * saves are atomic (tmp dir + rename);
@@ -11,13 +12,16 @@ from __future__ import annotations
 
 import io
 import json
+import math
 import shutil
+import struct
 import threading
 import time
 from pathlib import Path
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, Iterator, Optional, Tuple
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 PyTree = Any
@@ -27,13 +31,33 @@ _SEP = "::"
 _NPZ_SAVABLE = {"float64", "float32", "float16", "int64", "int32", "int16",
                 "int8", "uint8", "uint16", "uint32", "uint64", "bool"}
 
+# CSP wire format: magic (format and version), header length, JSON header,
+# then each leaf's raw bytes at an offset from the data start aligned to
+# _ALIGN; the data starts at the header's end rounded up to _ALIGN.
+_MAGIC = b"TRFCSP01"
+_PREFIX = struct.Struct("<8sQ")
+_ALIGN = 64
+
+
+def _aligned(n: int) -> int:
+    return -(-n // _ALIGN) * _ALIGN
+
+
+def _key(path) -> str:
+    return _SEP.join(str(getattr(k, "key", getattr(k, "idx", k))) for k in path)
+
+
+def _host_leaves(tree: PyTree) -> Iterator[Tuple[str, np.ndarray]]:
+    """(key, host array) per leaf, each copy a ``truffle.csp.d2h``."""
+    for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
+        with jax.profiler.TraceAnnotation("truffle.csp.d2h"):
+            v = np.asarray(leaf)
+        yield _key(path), v
+
 
 def _flatten(tree: PyTree) -> Dict[str, np.ndarray]:
     flat = {}
-    for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
-        key = _SEP.join(str(getattr(k, "key", getattr(k, "idx", k))) for k in path)
-        with jax.profiler.TraceAnnotation("truffle.csp.d2h"):
-            v = np.asarray(leaf)
+    for key, v in _host_leaves(tree):
         if str(v.dtype) not in _NPZ_SAVABLE:   # bf16 etc. -> widen for npz
             with jax.profiler.TraceAnnotation("truffle.csp.widen"):
                 v = v.astype(np.float32)
@@ -42,40 +66,66 @@ def _flatten(tree: PyTree) -> Dict[str, np.ndarray]:
 
 
 def _unflatten_into(like: PyTree, flat: Dict[str, np.ndarray]) -> PyTree:
+    """``like``'s structure from ``flat``; a leaf whose dtype differs from
+    ``like``'s is cast (``truffle.csp.narrow``), any other passes as it is."""
     paths = jax.tree_util.tree_flatten_with_path(like)
     leaves = []
     for path, leaf in paths[0]:
-        key = _SEP.join(str(getattr(k, "key", getattr(k, "idx", k))) for k in path)
+        key = _key(path)
         if key not in flat:
             raise KeyError(f"checkpoint missing leaf {key}")
-        if hasattr(leaf, "dtype"):
+        v = flat[key]
+        if hasattr(leaf, "dtype") and v.dtype != leaf.dtype:
             with jax.profiler.TraceAnnotation("truffle.csp.narrow"):
-                leaves.append(flat[key].astype(leaf.dtype))
-        else:
-            leaves.append(flat[key])
+                v = v.astype(leaf.dtype)
+        leaves.append(v)
     return jax.tree_util.tree_unflatten(paths[1], leaves)
 
 
 def serialize(tree: PyTree) -> bytes:
-    """Whole-tree bytes (CSP payloads, storage uploads). Its phases are
-    ``truffle.csp.*`` profiler spans: per leaf ``d2h`` (to host) and
-    ``widen`` (dtypes npz cannot hold, to float32), then ``pack``."""
+    """Whole-tree bytes (CSP payloads, storage uploads), each leaf in the
+    dtype it has: per leaf ``truffle.csp.d2h`` (to host), then one
+    ``truffle.csp.pack``, the only copy of the leaves' bytes. Layout:
+    ``_MAGIC``, the header's length (little-endian uint64), a JSON header
+    ``{"leaves": [[key, dtype, shape, offset], ...]}``, padding to
+    ``_ALIGN``, then the leaves, each at ``offset`` from that point and
+    padded to ``_ALIGN``."""
     with jax.profiler.TraceAnnotation("truffle.csp.serialize"):
-        flat = _flatten(tree)
+        leaves, raw, offset = [], [], 0
+        for key, v in _host_leaves(tree):
+            leaves.append([key, str(v.dtype), list(v.shape), offset])
+            # C-order bytes (a copy only if v is strided); bf16 exports no buffer
+            raw.append(v.reshape(-1).view(np.uint8))
+            offset += _aligned(v.nbytes)
         with jax.profiler.TraceAnnotation("truffle.csp.pack"):
-            buf = io.BytesIO()
-            np.savez(buf, **flat)
-            del flat            # free the widened leaves before the copy out
-            return buf.getvalue()
+            header = json.dumps({"leaves": leaves}).encode()
+            start = _PREFIX.size + len(header)
+            parts = [_PREFIX.pack(_MAGIC, len(header)), header,
+                     bytes(_aligned(start) - start)]
+            for r in raw:
+                parts += [r, bytes(_aligned(r.nbytes) - r.nbytes)]
+            return b"".join(parts)
 
 
 def deserialize(data: bytes, like: PyTree) -> PyTree:
     """The tree ``serialize`` wrote, as host arrays in ``like``'s dtypes:
-    ``truffle.csp.unpack``, then per leaf ``truffle.csp.narrow``."""
+    ``truffle.csp.unpack`` (the header, and a view per leaf), then
+    ``truffle.csp.narrow`` for each leaf stored in another dtype. A leaf in
+    its stored dtype is a read-only view over ``data``, not a copy. Raises
+    ``ValueError`` for bytes of another format (such as npz), ``KeyError``
+    for a leaf of ``like`` the payload lacks."""
     with jax.profiler.TraceAnnotation("truffle.csp.deserialize"):
         with jax.profiler.TraceAnnotation("truffle.csp.unpack"):
-            with np.load(io.BytesIO(data)) as z:
-                flat = {k: z[k] for k in z.files}
+            if len(data) < _PREFIX.size or data[:len(_MAGIC)] != _MAGIC:
+                raise ValueError("not a CSP payload: bad magic")
+            _, n = _PREFIX.unpack_from(data)
+            header = json.loads(bytes(data[_PREFIX.size:_PREFIX.size + n]))
+            base = _aligned(_PREFIX.size + n)
+            flat = {}
+            for key, dtype, shape, offset in header["leaves"]:
+                flat[key] = np.frombuffer(
+                    data, jnp.dtype(dtype), count=math.prod(shape),
+                    offset=base + offset).reshape(shape)
         return _unflatten_into(like, flat)
 
 

@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.checkpoint.checkpoint import CheckpointManager, deserialize, serialize
+from repro.checkpoint.checkpoint import CheckpointManager
 from repro.configs.base import ShapeConfig
 from repro.configs.registry import get_config
 from repro.core.buffer import Buffer

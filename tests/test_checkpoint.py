@@ -1,5 +1,9 @@
 """Checkpointing: roundtrip equality, atomicity/rotation, async saves,
-restore-latest, byte-stream serialize (the CSP payload path)."""
+restore-latest, byte-stream serialize (the CSP payload path) and its raw
+typed wire format."""
+import contextlib
+import io
+import struct
 import threading
 
 import jax
@@ -9,6 +13,8 @@ import pytest
 
 from repro.checkpoint.checkpoint import (CheckpointManager, deserialize,
                                          serialize)
+
+_HEAD = struct.Struct("<8sQ")    # magic, header length
 
 
 def _state(seed=0):
@@ -76,3 +82,95 @@ def test_restore_missing_raises(tmp_path):
     mgr = CheckpointManager(tmp_path)
     with pytest.raises(FileNotFoundError):
         mgr.restore(_state())
+
+
+_RNG = np.random.default_rng(0)
+LEAVES = {
+    "bf16": jnp.asarray(_RNG.normal(size=(3, 5)), jnp.bfloat16),
+    "f16": jnp.asarray(_RNG.normal(size=(7,)), jnp.float16),
+    "f32": jnp.asarray(_RNG.normal(size=(2, 3, 4)), jnp.float32),
+    "int8": jnp.asarray(_RNG.integers(-128, 128, size=(9,)), jnp.int8),
+    "int32": jnp.asarray(_RNG.integers(-2**31, 2**31, size=(4, 2)), jnp.int32),
+    "bool": jnp.asarray(_RNG.integers(0, 2, size=(11,)), jnp.bool_),
+    "0-d": jnp.asarray(1.25, jnp.bfloat16),
+    "empty": jnp.zeros((0, 4), jnp.bfloat16),
+    "transposed": np.arange(24, dtype=np.float32).reshape(4, 6).T,
+    "scalar": 7,
+}
+
+
+def _bits(x):
+    return np.ascontiguousarray(np.asarray(x)).view(np.uint8).tobytes()
+
+
+@pytest.mark.parametrize("name", list(LEAVES))
+def test_wire_roundtrip_is_bit_exact(name):
+    leaf = LEAVES[name]
+    # an odd-sized neighbour on each side, so every offset is exercised
+    tree = {"a": jnp.arange(3, dtype=jnp.int8), "leaf": leaf,
+            "z": jnp.ones((5,), jnp.bfloat16)}
+    back = deserialize(serialize(tree), like=tree)
+    for key, want in tree.items():
+        got = back[key]
+        assert np.shape(got) == np.shape(want)
+        assert got.dtype == np.asarray(want).dtype
+        assert _bits(got) == _bits(want), key
+
+
+def test_payload_is_header_plus_aligned_leaves():
+    tree = {k: v for k, v in LEAVES.items() if k != "scalar"}
+    payload = serialize(tree)
+    magic, n = _HEAD.unpack_from(payload)
+    aligned = lambda b: -(-b // 64) * 64
+    data = sum(aligned(np.asarray(v).nbytes) for v in tree.values())
+    assert magic == b"TRFCSP01"
+    assert len(payload) == aligned(_HEAD.size + n) + data
+
+
+def test_leaves_come_back_as_read_only_views():
+    tree = {"k": LEAVES["bf16"], "n": LEAVES["int32"]}
+    back = deserialize(serialize(tree), like=tree)
+    for v in back.values():
+        assert not v.flags.writeable and not v.flags.owndata
+
+
+def test_missing_leaf_raises_key_error():
+    payload = serialize({"a": LEAVES["f32"]})
+    with pytest.raises(KeyError, match="b"):
+        deserialize(payload, like={"a": LEAVES["f32"], "b": LEAVES["f32"]})
+
+
+def _npz_payload():
+    buf = io.BytesIO()
+    np.savez(buf, a=np.ones(3, np.float32))
+    return buf.getvalue()
+
+
+@pytest.mark.parametrize("payload", [_npz_payload(), b"", b"TRFCSP",
+                                     b"\x00" * 64], ids=["npz", "empty",
+                                                         "short", "zeros"])
+def test_foreign_payload_raises_value_error(payload):
+    with pytest.raises(ValueError, match="not a CSP payload"):
+        deserialize(payload, like={"a": np.ones(3, np.float32)})
+
+
+def test_dtype_mismatch_casts_under_one_narrow(monkeypatch):
+    names = []
+    real = jax.profiler.TraceAnnotation
+
+    @contextlib.contextmanager
+    def annotation(name, **kw):
+        names.append(name)
+        with real(name, **kw):
+            yield
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", annotation)
+    stored = {"k": LEAVES["f32"], "n": LEAVES["int32"]}
+    payload = serialize(stored)
+    del names[:]
+    like = {"k": jnp.zeros((2, 3, 4), jnp.bfloat16), "n": LEAVES["int32"]}
+    back = deserialize(payload, like=like)
+    assert names == ["truffle.csp.deserialize", "truffle.csp.unpack",
+                     "truffle.csp.narrow"]
+    assert back["k"].dtype == jnp.bfloat16
+    assert _bits(back["k"]) == _bits(np.asarray(stored["k"]).astype(jnp.bfloat16))
+    assert _bits(back["n"]) == _bits(stored["n"])
