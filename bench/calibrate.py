@@ -50,17 +50,17 @@ def main(argv=None) -> int:
 
     use_compilation_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    c, cfg = model.canonical(config), model.program_config(config)
-    shapes = model.weight_shapes(cfg)
+    refmod = bench.reference(config)
+    c = model.canonical(config, refmod.KEYS)
+    cfg = model.program_config(config, c)
     drv = harness.driver(mix["driver"])
-    refmod = check.reference_module(config["bench"]["reference"])
     ref, low = refmod.reference(c), refmod.reference(c, fp8=True)
     cycles = math.ceil(mix["check_requests"] / mix["clients"])
     engine = None
     for seed in seeds:
         if engine is not None:
             engine.params = None
-        weights = jax.block_until_ready(model.make_weights(shapes, seed))
+        weights = jax.block_until_ready(model.make_weights(cfg, seed))
         if engine is None:
             engine = ServeEngine(cfg, weights, max_batch=mix["clients"],
                                  max_len=mix["prompt_len"] + mix["new_tokens"])

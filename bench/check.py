@@ -14,14 +14,10 @@ put first.
 """
 from __future__ import annotations
 
-import importlib.util
 from dataclasses import dataclass
-from pathlib import Path
 from typing import List, Sequence
 
 import numpy as np
-
-REFERENCE_DIR = Path(__file__).resolve().parent / "reference"
 
 
 @dataclass
@@ -29,16 +25,6 @@ class Served:
     """One finished request: its prompt and the tokens served for it."""
     prompt: List[int]
     tokens: List[int]
-
-
-def reference_module(name: str):
-    path = REFERENCE_DIR / f"{name}.py"
-    if not path.is_file():
-        raise KeyError(f"no reference {name!r} at {path}")
-    spec = importlib.util.spec_from_file_location(f"bench_reference_{name}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
 
 
 def sample(finished: Sequence[Served], n: int, seed: int) -> List[Served]:

@@ -8,7 +8,10 @@ is a file of its own, found by the name ``BENCHMARK.json`` gives it:
   bench/drivers/<driver>.py      how a mix's requests reach the engine
   bench/metrics/<metric>.py      a per-layer metric's reader
   bench/limits/<cell>.json       the limit the correctness check holds
-  bench/reference/<name>.py      a configuration's plain reference
+  bench/reference/<name>.py      a configuration's plain reference, and the
+                                 sizes it takes (``KEYS``)
+  bench/work/<name>.py           the operations and bytes of its prefill and
+                                 decode steps, under the reference's name
 
 A run: refuse anything but a TPU; make the weights from the seed; build the
 engine through the program's own path and warm the cell's shapes (set-up);
@@ -19,6 +22,7 @@ sample of the finished requests against the reference; print one JSON line.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import importlib.util
 import json
 import sys
@@ -40,10 +44,10 @@ class NoAccelerator(RuntimeError):
 
 
 # --------------------------------------------------------------- resolution
-def _load_module(kind: str, name: str) -> ModuleType:
-    path = BENCH_DIR / kind / f"{name}.py"
+def _load_module(kind: str, name: str, base: Path = BENCH_DIR) -> ModuleType:
+    path = base / kind / f"{name}.py"
     if not path.is_file():
-        raise KeyError(f"no {kind[:-1]} {name!r} (looked for {path})")
+        raise KeyError(f"no {kind.rstrip('s')} {name!r} (looked for {path})")
     spec = importlib.util.spec_from_file_location(f"bench_{kind}_{name}", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
@@ -98,6 +102,24 @@ class Bench:
 
     def limit(self, cell: str) -> Dict[str, Any]:
         return self._data("limits", cell)
+
+    def reference(self, config: Dict[str, Any]) -> ModuleType:
+        """The configuration's plain reference, ``bench/reference/<name>.py``."""
+        return _load_module("reference", config["bench"]["reference"],
+                            self.root / "bench")
+
+    def work(self, config: Dict[str, Any]) -> ModuleType:
+        """The configuration's work count, ``bench/work/<name>.py`` under its
+        reference's name."""
+        return _load_module("work", config["bench"]["reference"],
+                            self.root / "bench")
+
+    def canonical(self, config: Dict[str, Any]) -> Dict[str, Any]:
+        """The sizes the configuration states, checked against its
+        reference's ``KEYS`` (``bench.model.canonical``)."""
+        from bench import model
+
+        return model.canonical(config, self.reference(config).KEYS)
 
     def end_to_end(self, cell: str) -> List[Dict[str, Any]]:
         return [m for m in self.spec["end_to_end"]
@@ -203,14 +225,13 @@ def memory_peak_bytes(devs) -> Optional[int]:
 
 
 # --------------------------------------------------------------------- work
-def cycle_work(c: Dict[str, Any], mix: Dict[str, Any]):
-    """(prefill FLOPs, bytes), [(decode FLOPs, bytes) per step] of one cycle:
-    one prefill of the batch, then new_tokens - 1 decode steps."""
-    from bench import flops
-
+def cycle_work(work: ModuleType, c: Dict[str, Any], mix: Dict[str, Any]):
+    """(prefill FLOPs, bytes), [(decode FLOPs, bytes) per step] of one cycle
+    by the configuration's work count ``work``: one prefill of the batch,
+    then new_tokens - 1 decode steps."""
     B, L, n = mix["clients"], mix["prompt_len"], mix["new_tokens"]
-    return (flops.prefill(c, B, L),
-            [flops.decode_step(c, B, L + i + 1) for i in range(n - 1)])
+    return (work.prefill(c, B, L),
+            [work.decode_step(c, B, L + i + 1) for i in range(n - 1)])
 
 
 def end_to_end(window: Window, setup_s: float) -> Dict[str, float]:
@@ -257,7 +278,7 @@ def run(args: argparse.Namespace, t_start: float, bench: Optional[Bench] = None,
     import jax
 
     from bench import check, model
-    from repro.launch.compile_cache import use_compilation_cache
+    from repro.launch.compile_cache import compile_snapshot, use_compilation_cache
     from repro.serving.engine import ServeEngine
     marks.append(("import", time.monotonic()))
 
@@ -267,10 +288,10 @@ def run(args: argparse.Namespace, t_start: float, bench: Optional[Bench] = None,
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     kind = devs[0].device_kind
     peak = peak_row(kind)
-    c = model.canonical(config)
-    cfg = model.program_config(config)
-    weights = jax.block_until_ready(model.make_weights(model.weight_shapes(cfg),
-                                                       args.seed))
+    refmod = bench.reference(config)
+    c = model.canonical(config, refmod.KEYS)
+    cfg = model.program_config(config, c)
+    weights = jax.block_until_ready(model.make_weights(cfg, args.seed))
     marks.append(("weights", time.monotonic()))
     engine = ServeEngine(cfg, weights, max_batch=mix["clients"],
                          max_len=mix["prompt_len"] + mix["new_tokens"])
@@ -278,23 +299,27 @@ def run(args: argparse.Namespace, t_start: float, bench: Optional[Bench] = None,
     drv.warm(engine, traffic)
     marks.append(("warm", time.monotonic()))
     setup_s = time.monotonic() - t_start
+    setup = {"compile_s": engine.stats.compile_s}
     phases = " ".join(f"{n}={t - marks[i][1]:.3f}"
                       for i, (n, t) in enumerate(marks[1:]))
+    compiles = compile_snapshot()
     log(f"bench: {cell['name']} seed={args.seed} set-up {setup_s:.3f} s: {phases} "
-        f"(engine compile {engine.stats.compile_s:.3f}; compile cache {cache_dir})")
+        f"(engine compile {engine.stats.compile_s:.3f}; compile cache {cache_dir}: "
+        f"{_counts(compiles)})")
 
     summary = None
     if args.trace:
         summary, _ = _trace_cycles(drv, engine, traffic, mix["trace_cycles"])
-    s0 = _stats(engine)
+    s0, c0 = _stats(engine), compile_snapshot()
     window = run_cycles(drv, engine, traffic, seconds=args.seconds)
-    s1 = _stats(engine)
+    s1, c1 = _stats(engine), compile_snapshot()
     mem = memory_peak_bytes(devs)
     log(f"bench: window {window.seconds:.3f} s, {window.cycles} cycles, "
-        f"{len(window.records)} requests")
+        f"{len(window.records)} requests; in the window "
+        f"{_counts({k: c1[k] - c0[k] for k in c1})}")
 
     engine.last_state = None                 # free the served state
-    ref = check.reference_module(config["bench"]["reference"]).reference(c)
+    ref = refmod.reference(c)
     picked = check.sample([check.Served(r.prompt, r.tokens) for r in window.records],
                           mix["check_requests"], args.seed)
     gaps = check.served_gaps(ref, weights, picked) if picked else np.array([np.inf])
@@ -308,8 +333,8 @@ def run(args: argparse.Namespace, t_start: float, bench: Optional[Bench] = None,
     if args.trace:
         metrics = _per_layer(readers, SimpleNamespace(
             cell=cell, mix=mix, c=c, peak=peak, window=window, trace=summary,
-            stats={k: s1[k] - s0[k] for k in s0}, memory_peak_bytes=mem,
-            work=cycle_work(c, mix)))
+            stats={k: s1[k] - s0[k] for k in s0}, setup=setup,
+            memory_peak_bytes=mem, work=cycle_work(bench.work(config), c, mix)))
         device.update(busy_s=summary.busy_s, window_s=summary.window_s)
     else:
         values = end_to_end(window, setup_s)
@@ -328,9 +353,14 @@ def run(args: argparse.Namespace, t_start: float, bench: Optional[Bench] = None,
 
 
 def _stats(engine) -> Dict[str, float]:
-    s = engine.stats
-    return {"prefill_s": s.prefill_s, "decode_s": s.decode_s,
-            "tokens_out": s.tokens_out}
+    """Every numeric field of the engine's ``EngineStats``."""
+    s = dataclasses.asdict(engine.stats)
+    return {k: v for k, v in s.items()
+            if isinstance(v, (int, float)) and not isinstance(v, bool)}
+
+
+def _counts(compiles: Dict[str, float]) -> str:
+    return " ".join(f"{k}={v:g}" for k, v in compiles.items())
 
 
 def _per_layer(readers, ctx) -> Dict[str, Dict[str, Any]]:

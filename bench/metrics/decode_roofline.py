@@ -1,5 +1,5 @@
 """Share of its roofline the compiled decode step reaches: the least time of
-the traced steps (``bench/flops.py``, each step over its filled cache slots)
+the traced steps (``bench/work/``, each step over its filled cache slots)
 over their device time. ``ServeEngine.warmup`` jits the step as a lambda,
 whose XLA module is ``jit__lambda``."""
 from bench.metrics._window import least, roofline

@@ -3,10 +3,15 @@
 A configuration file (``bench/configs/<name>.json``) holds the model's
 published ``config.json`` keys at its top level, as run, and a ``bench``
 object: the source, the program's registry entry it starts from, the name of
-its plain reference (``bench/reference/<name>.py``), and ``keys``, which
-names the published key behind each size the program and the reference take.
-Architecture facts that ``config.json`` has no key for sit under
-``bench.architecture``.
+its plain reference (``bench/reference/<name>.py``, whose work count is
+``bench/work/<name>.py``), and ``keys``, which names the published key behind
+each size the program and the reference take. Architecture facts that
+``config.json`` has no key for sit under ``bench.architecture``.
+
+The reference declares the sizes it takes (``KEYS``); the file has to state
+each of them, and no other. Each reaches the program's ``ModelConfig`` field
+of that name; a dotted name (``mla.kv_lora_rank``) reaches into the nested
+dataclass of its first part.
 
 Weights are made here, from the seed, in one jitted call on the device and in
 the dtype they are served in. The program and the reference read the same
@@ -14,50 +19,64 @@ arrays; neither makes them.
 """
 from __future__ import annotations
 
-from typing import Any, Dict
+import dataclasses
+from typing import Any, Dict, Sequence
 
 import jax
 import jax.numpy as jnp
-
-# Sizes and switches both the program and the reference take, by the name the
-# reference uses; each comes from bench.keys (a published key) or
-# bench.architecture.
-CANONICAL = ("num_layers", "d_model", "num_heads", "num_kv_heads", "head_dim",
-             "d_ff", "vocab_size", "norm_eps", "rope_theta", "tie_embeddings",
-             "qkv_bias", "qk_norm", "partial_rotary")
 
 # Spread of the random weights: unit-scale activations through every layer,
 # logits with a standard deviation near 1, and norm scales and biases that
 # are not their identity, so that a path that drops them shows.
 NORM_SCALE_STD = 0.1
 BIAS_STD = 0.2
+# The leading axis ``stack_defs`` gives a parameter repeated per layer.
+STACK_AXIS = "layers"
 
 
-def canonical(config: Dict[str, Any]) -> Dict[str, Any]:
-    """The sizes the program and the reference are built from."""
+def canonical(config: Dict[str, Any], keys: Sequence[str]) -> Dict[str, Any]:
+    """The sizes the program and the reference are built from: exactly
+    ``keys``, the reference's ``KEYS``, and the served dtype."""
     bench = config["bench"]
     out = {k: config[v] for k, v in bench["keys"].items()}
     for k, v in bench["architecture"].items():
         if k != "why":
             out[k] = v
-    missing = [k for k in CANONICAL if k not in out]
-    if missing:
-        raise ValueError(f"configuration lacks {missing}")
+    missing = [k for k in keys if k not in out]
+    extra = [k for k in out if k not in keys]
+    if missing or extra:
+        raise ValueError(f"configuration lacks {missing} and states {extra} "
+                         f"that its reference does not take")
     out["dtype"] = config["torch_dtype"]
     return out
 
 
-def program_config(config: Dict[str, Any]):
+def _replace(obj, key: str, value):
+    """``obj`` with the field ``key`` (dotted: a nested dataclass's) set."""
+    head, _, rest = key.partition(".")
+    names = {f.name for f in dataclasses.fields(obj)} \
+        if dataclasses.is_dataclass(obj) else set()
+    if head not in names:
+        raise ValueError(f"the program's {type(obj).__name__} has no field "
+                         f"{head!r} (for {key!r})")
+    if rest:
+        value = _replace(getattr(obj, head), rest, value)
+    return dataclasses.replace(obj, **{head: value})
+
+
+def program_config(config: Dict[str, Any], c: Dict[str, Any]):
     """The program's ModelConfig: its registry entry, served in the compute
-    dtype (``serving_config``), with every size the file states."""
+    dtype (``serving_config``), with every size of ``c`` (``canonical``)."""
     from repro.launch.serve import serving_config
 
-    c = canonical(config)
     cfg = serving_config(config["bench"]["arch"], smoke=False)
     if cfg.dtype != c["dtype"]:
         raise ValueError(f"program computes in {cfg.dtype}, the configuration "
                          f"states {c['dtype']}")
-    return cfg.replace(**{k: c[k] for k in CANONICAL})
+    for k, v in c.items():
+        if k != "dtype":
+            cfg = _replace(cfg, k, v)
+    return cfg
 
 
 def weight_shapes(cfg) -> Dict[str, Any]:
@@ -67,33 +86,44 @@ def weight_shapes(cfg) -> Dict[str, Any]:
     return api.abstract(cfg)
 
 
-def _leaf_init(path) -> str:
-    name = str(getattr(path[-1], "key", path[-1]))
-    parent = str(getattr(path[-2], "key", "")) if len(path) > 1 else ""
-    if name in ("q_norm", "k_norm") or (name == "scale" and parent.endswith("norm")):
+def _leaf_init(path, d) -> str:
+    """How the benchmark draws one leaf, from the program's ``ParamDef``:
+    scales (``ones``) and biases (``zeros``) off their identity, a table
+    looked up by token id (first axis ``vocab``) by its width, and every
+    other matrix by its fan-in."""
+    if d.init == "ones":
         return "norm"
-    if name in ("bq", "bk", "bv"):
+    if d.init == "zeros":
         return "bias"
-    if name == "embedding":
-        return "embedding"
-    if name.startswith("w") or name == "unembed":
-        return "dense"
-    raise ValueError(f"no initialiser for parameter {'/'.join(map(str, path))}")
+    if d.init == "normal":
+        if d.axes[0] == "vocab":
+            return "embedding"
+        if len([a for a in d.axes if a != STACK_AXIS]) >= 2:
+            return "dense"
+    raise ValueError(f"no initialiser for parameter "
+                     f"{jax.tree_util.keystr(path)} ({d.init}, axes {d.axes})")
 
 
-def make_weights(shapes, seed: int):
-    """Every leaf of ``shapes`` drawn from ``seed`` in one jitted call."""
-    return weights_program(shapes)(seed_key(seed))
+def make_weights(cfg, seed: int):
+    """Every leaf of the program's parameter tree drawn from ``seed`` in one
+    jitted call."""
+    return weights_program(cfg)(seed_key(seed))
 
 
-def weights_program(shapes):
-    """The jitted function of a PRNG key that makes the weights."""
-    paths, treedef = jax.tree_util.tree_flatten_with_path(shapes)
-    specs = [(p, _leaf_init(p), s.shape, s.dtype) for p, s in paths]
+def weights_program(cfg):
+    """The jitted function of a PRNG key that makes the weights, leaf ``i``
+    of the tree (in its flattening order) from ``fold_in(key, i)``."""
+    from repro.models import api
+    from repro.models.params import ParamDef
+
+    paths, treedef = jax.tree_util.tree_flatten_with_path(
+        api.model_defs(cfg), is_leaf=lambda x: isinstance(x, ParamDef))
+    specs = [(_leaf_init(p, d), d.shape, jnp.dtype(d.dtype or cfg.param_dtype))
+             for p, d in paths]
 
     def bench_weights(key):
         leaves = []
-        for i, (_, kind, shape, dtype) in enumerate(specs):
+        for i, (kind, shape, dtype) in enumerate(specs):
             z = jax.random.normal(jax.random.fold_in(key, i), shape, jnp.float32)
             if kind == "norm":
                 z = 1.0 + NORM_SCALE_STD * z

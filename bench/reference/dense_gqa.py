@@ -37,6 +37,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+# The sizes and switches this reference takes, by the names it reads them
+# under; a configuration file states each (``bench.model.canonical``).
+KEYS = ("num_layers", "d_model", "num_heads", "num_kv_heads", "head_dim",
+        "d_ff", "vocab_size", "norm_eps", "rope_theta", "tie_embeddings",
+        "qkv_bias", "qk_norm", "partial_rotary")
+
 HIGHEST = jax.lax.Precision.HIGHEST
 F32 = jnp.float32
 # Bytes of attention scores one call may hold; rows are processed in blocks.
@@ -70,7 +76,8 @@ def _rope(x, cos, sin):
 
 
 class Reference:
-    """Float32 forward pass of one configuration (``bench.model.canonical``)."""
+    """Float32 forward pass of one configuration (``bench.model.canonical``
+over ``KEYS``)."""
 
     def __init__(self, c: Dict[str, Any], fp8: bool = False):
         self.c = dict(c)

@@ -35,7 +35,7 @@ def rehearse(cell_name: str) -> dict:
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
-    from bench import check, model
+    from bench import model
     from bench.harness import Bench
     from repro.models import api
     from repro.serving.engine import ServeEngine
@@ -49,7 +49,9 @@ def rehearse(cell_name: str) -> dict:
     bench = Bench()
     cell = bench.cell(cell_name)
     config, mix = bench.config(cell["config"]), bench.mix(cell["traffic"])
-    c, cfg = model.canonical(config), model.program_config(config)
+    refmod = bench.reference(config)
+    c = model.canonical(config, refmod.KEYS)
+    cfg = model.program_config(config, c)
     B, L, n = mix["clients"], mix["prompt_len"], mix["new_tokens"]
     shapes = model.weight_shapes(cfg)
     p_sds = jax.tree.map(sds, shapes)
@@ -62,7 +64,7 @@ def rehearse(cell_name: str) -> dict:
         print(cell_name, name, out[name], file=sys.stderr, flush=True)
 
     key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one)
-    timed("weights", model.weights_program(shapes).lower(key))
+    timed("weights", model.weights_program(cfg).lower(key))
     engine = ServeEngine(cfg, None, max_batch=B, max_len=L + n)
 
     def prefill(p, b):
@@ -75,7 +77,6 @@ def rehearse(cell_name: str) -> dict:
         p_sds, jax.tree.map(sds, api.cache_sds(cfg, B, L + n)),
         jax.ShapeDtypeStruct((B, 1), jnp.int32, sharding=one),
         jax.ShapeDtypeStruct((), jnp.int32, sharding=one)))
-    refmod = check.reference_module(config["bench"]["reference"])
     ref = refmod.Reference(c)
     T = L + n - 1
     rows = max(1, min(mix["check_requests"],

@@ -3,8 +3,9 @@
 ``load`` turns the ``.xplane.pb`` that ``jax.profiler`` writes into plain
 lists of ``[name, start_ns, duration_ns]``: per device plane (``/device:TPU:N``)
 its ``XLA Ops`` and ``XLA Modules`` lines, and the host's ``bench.*`` spans
-(the harness's ``TraceAnnotation``s). ``reduce`` works on those lists alone,
-so a recorded trace in that form checks it without a chip.
+(the harness's ``TraceAnnotation``s) and ``truffle.*`` spans (the program's).
+``reduce`` works on those lists alone, so a recorded trace in that form
+checks it without a chip.
 """
 from __future__ import annotations
 
@@ -18,7 +19,7 @@ from typing import Dict, List, Tuple
 DEVICE_PREFIX = "/device:TPU:"
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
-SPAN_PREFIX = "bench."
+SPAN_PREFIXES = ("bench.", "truffle.")
 WINDOW_SPAN = "bench.traced"
 TOP = 10
 
@@ -46,7 +47,7 @@ def load(log_dir: str | Path) -> Dict:
             for line in plane.lines:
                 out["host"].extend([e.name, int(e.start_ns), int(e.duration_ns)]
                                    for e in line.events
-                                   if e.name.startswith(SPAN_PREFIX))
+                                   if e.name.startswith(SPAN_PREFIXES))
     return out
 
 
@@ -68,6 +69,8 @@ class Summary:
     modules: Dict[str, Tuple[float, int]]          # name -> (device s, calls)
     device_ops: List[Tuple[str, float]] = field(default_factory=list)
     idle_gaps: List[Tuple[str, float]] = field(default_factory=list)
+    # host span name -> (seconds inside the window, spans that overlap it)
+    spans: Dict[str, Tuple[float, int]] = field(default_factory=dict)
 
     def module_time(self, prefix: str) -> Tuple[float, int]:
         """Device seconds and calls of the modules whose name starts with
@@ -80,8 +83,8 @@ class Summary:
 
 
 def reduce(trace: Dict) -> Summary:
-    """Busy time, per-module device time, top ops and idle gaps inside the
-    ``bench.traced`` span."""
+    """Busy time, per-module device time, top ops, idle gaps and the host
+    spans' time inside the ``bench.traced`` span."""
     spans = [h for h in trace["host"] if h[0] == WINDOW_SPAN]
     if not spans:
         raise ValueError(f"trace has no {WINDOW_SPAN} span")
@@ -100,6 +103,10 @@ def reduce(trace: Dict) -> Summary:
     busy, modules, ops = [], defaultdict(lambda: [0.0, 0]), defaultdict(float)
     gaps_by: Dict[str, float] = defaultdict(float)
     host = [(n, s, s + d) for n, s, d in trace["host"] if n != WINDOW_SPAN]
+    spans: Dict[str, List] = defaultdict(lambda: [0, 0])
+    for name, a, b in clip((n, s, e - s) for n, s, e in host):
+        spans[name][0] += b - a
+        spans[name][1] += 1
     bounds = sorted({t for _, s, e in host for t in (s, e)})
     for dev in devices:
         merged = _union([(a, b) for _, a, b in clip(dev["ops"])])
@@ -117,7 +124,8 @@ def reduce(trace: Dict) -> Summary:
     gaps = sorted(((n, t / 1e9) for n, t in gaps_by.items()), key=lambda x: -x[1])
     return Summary(window_s=(t1 - t0) / 1e9, busy_s=sum(busy) / len(busy) / 1e9,
                    modules={n: (t / 1e9, c) for n, (t, c) in modules.items()},
-                   device_ops=top_ops[:TOP], idle_gaps=gaps[:TOP])
+                   device_ops=top_ops[:TOP], idle_gaps=gaps[:TOP],
+                   spans={n: (ns / 1e9, k) for n, (ns, k) in spans.items()})
 
 
 def _split(gaps: List[Tuple[int, int]], bounds: List[int]):
@@ -130,9 +138,9 @@ def _split(gaps: List[Tuple[int, int]], bounds: List[int]):
 
 
 def _attribute(host: List[Tuple[str, int, int]], gaps: List[Tuple[int, int]]):
-    """(innermost ``bench.*`` span the host was in, length) for each piece
-    of idle time, in time order, taken at its midpoint. The harness's spans
-    nest."""
+    """(innermost ``bench.*`` or ``truffle.*`` span the host was in, length)
+    for each piece of idle time, in time order, taken at its midpoint. The
+    harness's spans nest, and the program's nest inside them."""
     spans = sorted(host, key=lambda h: (h[1], -h[2]))
     stack: List[Tuple[str, int, int]] = []
     i = 0

@@ -13,7 +13,7 @@ import pytest
 ROOT = Path(__file__).resolve().parents[2]
 sys.path[:0] = [str(ROOT), str(Path(__file__).resolve().parent)]
 
-from bench import check, model  # noqa: E402
+from bench import harness, model  # noqa: E402
 import tiny  # noqa: E402
 from repro.serving.engine import ServeEngine  # noqa: E402
 
@@ -22,11 +22,11 @@ L, STEPS, B = 10, 4, 2
 
 def _program_and_reference(name, dtype):
     config = tiny.tiny_config(name)
-    c = model.canonical(config)
-    cfg = model.program_config(config).replace(dtype=dtype, param_dtype=dtype)
-    weights = model.make_weights(model.weight_shapes(cfg), 11)
-    ref = check.reference_module(config["bench"]["reference"]).Reference(c)
-    return cfg, weights, ref, c
+    refmod = harness.Bench().reference(config)
+    c = model.canonical(config, refmod.KEYS)
+    cfg = model.program_config(config, c).replace(dtype=dtype, param_dtype=dtype)
+    weights = model.make_weights(cfg, 11)
+    return cfg, weights, refmod.Reference(c), c
 
 
 def _program_logits(cfg, weights, toks):
@@ -61,8 +61,7 @@ def test_bf16_program_within_rounding_of_reference(name):
     got = _program_logits(cfg, weights, toks)
     pos = list(range(L - 1, L + STEPS))
     want = ref.logits(weights, toks, pos)
-    low = check.reference_module("dense_gqa").Reference(c, fp8=True).logits(
-        weights, toks, pos)
+    low = type(ref)(c, fp8=True).logits(weights, toks, pos)
     err, err8 = np.abs(got - want).max(), np.abs(low - want).max()
     assert err < 0.1 * want.std()
     assert err8 > 2 * err

@@ -1,6 +1,8 @@
 """BENCHMARK.json against the benchmark's contract, and resolution by name."""
 from __future__ import annotations
 
+import functools
+import hashlib
 import json
 import os
 import re
@@ -15,10 +17,14 @@ import pytest
 ROOT = Path(__file__).resolve().parents[2]
 sys.path[:0] = [str(ROOT), str(Path(__file__).resolve().parent)]
 
-from bench import flops, harness, model  # noqa: E402
+from bench import harness, model  # noqa: E402
 import tiny  # noqa: E402
 
 SPEC = json.loads((ROOT / "BENCHMARK.json").read_text())
+# The weights and work counts the existing cells were measured and their
+# limits calibrated with (``data/yardstick_before_files.json``).
+BEFORE = json.loads((ROOT / "tests" / "bench" / "data" /
+                     "yardstick_before_files.json").read_text())
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
 LINE = re.compile(r"^[^\t\n]{1,200}$")
@@ -131,17 +137,56 @@ def test_configs_files_and_reductions():
             assert k in cfg
 
 
+def _program_value(cfg, key: str):
+    """The program's value of a size name; a dotted one is a nested field's."""
+    return functools.reduce(getattr, key.split("."), cfg)
+
+
 @pytest.mark.parametrize("name", sorted(f.stem for f in (ROOT / "bench" / "configs").glob("*.json")))
 def test_program_runs_what_the_file_states(name):
-    """Every size of the file reaches the program's ModelConfig, and the
-    benchmark's parameter count equals the program's parameter tree."""
-    config = harness.Bench().config(name)
-    c, cfg = model.canonical(config), model.program_config(config)
-    for k in model.CANONICAL:
-        assert getattr(cfg, k) == c[k], k
+    """Every size the file states, by its reference's ``KEYS``, reaches the
+    program's ModelConfig, and its work count's parameter count equals the
+    program's parameter tree."""
+    bench = harness.Bench()
+    config = bench.config(name)
+    keys = bench.reference(config).KEYS
+    c = model.canonical(config, keys)
+    cfg = model.program_config(config, c)
+    for k in keys:
+        assert _program_value(cfg, k) == c[k], k
     shapes = model.weight_shapes(cfg)
     n = sum(int(np.prod(s.shape)) for s in jax.tree.leaves(shapes))
-    assert n == flops.param_count(c)
+    assert n == bench.work(config).param_count(c)
+
+
+@pytest.mark.parametrize("name", sorted(BEFORE["weights_sha256"]))
+def test_weights_are_bitwise_the_calibrated_ones(name):
+    """The weights of a tiny copy of each configuration, drawn by the
+    program's parameter definitions, are bit for bit the ones the limits
+    were calibrated with."""
+    config = tiny.tiny_config(name)
+    cfg = model.program_config(config, harness.Bench().canonical(config))
+    h = hashlib.sha256()
+    for leaf in jax.tree.leaves(model.make_weights(cfg, BEFORE["seed"])):
+        h.update(np.asarray(leaf).tobytes())
+    assert h.hexdigest() == BEFORE["weights_sha256"][name]
+
+
+@pytest.mark.parametrize("cell", sorted(BEFORE["work"]))
+def test_work_counts_are_the_calibrated_ones(cell):
+    """Each cell's operations and bytes at its mix's shapes, by its
+    configuration's work module, equal exactly those its rooflines and
+    ``mfu`` were first read with."""
+    bench = harness.Bench()
+    w = bench.cell(cell)
+    config = bench.config(w["config"])
+    c, work = bench.canonical(config), bench.work(config)
+    pf, steps = harness.cycle_work(work, c, bench.mix(w["traffic"]))
+    want = BEFORE["work"][cell]
+    assert list(pf) == want["prefill"]
+    assert [list(s) for s in steps] == want["decode_steps"]
+    assert work.param_count(c) == want["param_count"]
+    assert work.kv_bytes_per_token(c) == want["kv_bytes_per_token"]
 
 
 def test_resolution_by_name():
@@ -173,6 +218,113 @@ def test_a_new_mix_needs_only_new_files(tmp_path):
     cell = bench.cell("qwen3-4b.longer")
     assert bench.mix(cell["traffic"])["prompt_len"] == 20
     assert harness.driver(bench.mix(cell["traffic"])["driver"]).cycle
+
+
+# Two architectures of the program's registry that no benchmark file covers,
+# at smoke widths: latent attention (MLA), and experts with shared ones.
+COMMON_KEYS = {"num_layers": ("num_hidden_layers", 2), "d_model": ("hidden_size", 64),
+               "num_heads": ("num_attention_heads", 4),
+               "num_kv_heads": ("num_key_value_heads", 4), "head_dim": ("head_dim", 16),
+               "d_ff": ("intermediate_size", 128), "vocab_size": ("vocab_size", 512),
+               "norm_eps": ("rms_norm_eps", 1e-6)}
+NEW_ARCHITECTURES = {
+    "minicpm3-4b": ("mla_stub", {
+        "mla.q_lora_rank": ("q_lora_rank", 32), "mla.kv_lora_rank": ("kv_lora_rank", 16),
+        "mla.qk_nope_head_dim": ("qk_nope_head_dim", 16),
+        "mla.qk_rope_head_dim": ("qk_rope_head_dim", 8),
+        "mla.v_head_dim": ("v_head_dim", 16)},
+        {"wq_a", "q_norm", "wq_b", "wkv_a", "kv_norm", "wkv_b", "wo"}),
+    "qwen2-moe-a2.7b": ("moe_stub", {
+        "moe.num_experts": ("num_experts", 6), "moe.top_k": ("num_experts_per_tok", 2),
+        "moe.d_expert": ("moe_intermediate_size", 64),
+        "moe.num_shared": ("num_shared_experts", 2), "qkv_bias": ("attention_bias", True)},
+        {"router", "w_gate", "w_up", "w_down", "shared"}),
+}
+STUB_WORK = """
+def param_count(c):
+    return 7
+
+
+def kv_bytes_per_token(c):
+    return 3
+
+
+def prefill(c, batch, seq):
+    return float(batch * seq * c["d_model"]), 11.0
+
+
+def decode_step(c, batch, kv_len):
+    return float(batch * kv_len), 13.0
+"""
+
+
+def _tree_files(path: Path):
+    return sorted((str(f.relative_to(path)), f.stat().st_mtime_ns)
+                  for f in path.rglob("*") if "__pycache__" not in f.parts)
+
+
+@pytest.mark.parametrize("arch", sorted(NEW_ARCHITECTURES))
+def test_a_new_architecture_needs_only_new_files(tmp_path, arch):
+    """A configuration of another architecture enters with new files alone:
+    its config, a reference that declares the sizes it takes (dotted ones
+    reach the program's nested MLAConfig or MoEConfig), and a work count.
+    Every size reaches the program, every leaf gets a weight, and the cell's
+    work comes from the new module; nothing under ``bench/`` is written."""
+    before = _tree_files(ROOT / "bench")
+    reference, own_keys, leaves = NEW_ARCHITECTURES[arch]
+    keys = {**COMMON_KEYS, **own_keys}
+    root = tiny.tiny_root(tmp_path, configs=("qwen3-4b",), mixes=("decode",))
+    config = {published: value for published, value in keys.values()}
+    config.update(torch_dtype="bfloat16", bench={
+        "source": "smoke widths", "arch": arch, "reference": reference,
+        "keys": {k: published for k, (published, _) in keys.items()},
+        "architecture": {"why": "none"}})
+    (root / "bench" / "configs" / f"{arch}.json").write_text(json.dumps(config))
+    (root / "bench" / "reference" / f"{reference}.py").write_text(
+        f"KEYS = {tuple(keys)!r}\n")
+    (root / "bench" / "work" / f"{reference}.py").write_text(STUB_WORK)
+    spec = json.loads((root / "BENCHMARK.json").read_text())
+    spec["workloads"].append({"name": f"{arch}.decode", "config": arch,
+                              "traffic": "decode", "chips": 1, "why": "new"})
+    (root / "BENCHMARK.json").write_text(json.dumps(spec))
+
+    bench = harness.Bench(root)
+    cell = bench.cell(f"{arch}.decode")
+    config = bench.config(cell["config"])
+    c = bench.canonical(config)
+    cfg = model.program_config(config, c)
+    for k, (_, value) in keys.items():
+        assert _program_value(cfg, k) == value, k
+    weights = model.make_weights(cfg, 2**33 + 3)
+    shapes = model.weight_shapes(cfg)
+    assert jax.tree.structure(weights) == jax.tree.structure(shapes)
+    for w, sh in zip(jax.tree.leaves(weights), jax.tree.leaves(shapes)):
+        assert (w.shape, w.dtype) == (sh.shape, sh.dtype)
+        assert np.isfinite(np.asarray(w, np.float32)).all()
+    block = weights["blocks"]["pos0"]
+    assert leaves <= set(block["mixer"]) | set(block["mlp"])
+    mix = bench.mix(cell["traffic"])
+    pf, steps = harness.cycle_work(bench.work(config), c, mix)
+    B, L = mix["clients"], mix["prompt_len"]
+    assert pf == (float(B * L * 64), 11.0)
+    assert steps == [(float(B * (L + i + 1)), 13.0) for i in range(mix["new_tokens"] - 1)]
+    assert _tree_files(ROOT / "bench") == before
+
+
+def test_sizes_are_checked_against_the_reference():
+    """A file that lacks a size its reference takes, or states one it does
+    not take, is refused; so is a size the program has no field for."""
+    bench = harness.Bench()
+    config = bench.config("qwen3-4b")
+    keys = bench.reference(config).KEYS
+    with pytest.raises(ValueError, match="lacks"):
+        model.canonical(config, keys + ("mla.kv_lora_rank",))
+    with pytest.raises(ValueError, match="does not take"):
+        model.canonical(config, keys[1:])
+    c = model.canonical(config, keys)
+    for bad in ("no_such_size", "mla.kv_lora_rank", "num_layers.x"):
+        with pytest.raises(ValueError, match="no field"):
+            model.program_config(config, dict(c, **{bad: 1}))
 
 
 def test_peaks_lookup():

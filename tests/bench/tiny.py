@@ -2,11 +2,12 @@
 
 ``tiny_root(tmp)`` writes a root that ``bench.harness.Bench`` reads: a
 BENCHMARK.json with one cell per (configuration, mix), each configuration
-cut to smoke widths through the published keys its file maps, and each mix
-cut to a few short requests."""
+cut to smoke widths through the published keys its file maps, each mix cut
+to a few short requests, and copies of the references and work counts."""
 from __future__ import annotations
 
 import json
+import shutil
 from pathlib import Path
 
 ROOT = Path(__file__).resolve().parents[2]
@@ -42,6 +43,10 @@ def tiny_root(tmp: Path, configs=("qwen3-4b", "glm4-9b-20l"),
     spec = json.loads((ROOT / "BENCHMARK.json").read_text())
     for kind in ("configs", "mixes", "limits"):
         (tmp / "bench" / kind).mkdir(parents=True, exist_ok=True)
+    for kind in ("reference", "work"):
+        shutil.copytree(ROOT / "bench" / kind, tmp / "bench" / kind,
+                        ignore=shutil.ignore_patterns("__pycache__"),
+                        dirs_exist_ok=True)
     cells = []
     for c in configs:
         (tmp / "bench" / "configs" / f"{c}.json").write_text(json.dumps(tiny_config(c)))
