@@ -15,8 +15,10 @@ from typing import Optional, Tuple
 
 @dataclass(frozen=True)
 class MLAConfig:
-    """Multi-head Latent Attention (DeepSeek-V2 / MiniCPM3 style)."""
-    q_lora_rank: int = 768
+    """Multi-head Latent Attention (DeepSeek-V2 / MiniCPM3 style).
+    ``q_lora_rank`` None: the query is one full-rank projection ``wq``
+    (DeepSeek-V2-Lite)."""
+    q_lora_rank: Optional[int] = 768
     kv_lora_rank: int = 256
     qk_nope_head_dim: int = 64
     qk_rope_head_dim: int = 32
@@ -32,6 +34,24 @@ class MoEConfig:
     capacity_factor: float = 1.25  # train-time token capacity per expert
     router_z_coef: float = 1e-3
     aux_loss_coef: float = 1e-2
+    norm_topk_prob: bool = True    # renormalise the top-k weights to sum to 1
+    # Experts the router scores. 0: the layer holds every expert it routes
+    # over, and serving keeps the training path's capacity dispatch. Set, the
+    # layer is one rank's share of expert parallelism: it holds experts
+    # [0, num_experts) of the router's ``router_experts``, adds only their
+    # part of the result, and serves without drops (``moe.moe_apply``).
+    router_experts: int = 0
+
+
+@dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rope scaling (DeepSeek-V2's ``rope_scaling`` of type yarn)."""
+    factor: float = 1.0
+    original_max_position_embeddings: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -88,12 +108,16 @@ class ModelConfig:
     rope_theta: float = 1e4
     partial_rotary: float = 1.0    # fraction of head_dim that is rotated
     mla: Optional[MLAConfig] = None
+    yarn: Optional[YarnConfig] = None
 
     # --- layer stack ---
     # One *superblock* period; each entry is (mixer, mlp):
     #   mixer in {attn, mamba, mlstm, slstm}; mlp in {mlp, moe, none, glu}
     # Dense default: (("attn", "mlp"),)
     block_pattern: Tuple[Tuple[str, str], ...] = (("attn", "mlp"),)
+    # Layers of (attn, mlp) before the periodic stack (DeepSeek's
+    # first_k_dense_replace), stacked on a leading axis of their own.
+    leading_dense_layers: int = 0
     norm: str = "rmsnorm"          # rmsnorm | layernorm
     norm_eps: float = 1e-5
     act: str = "silu"              # silu (SwiGLU MLP) | gelu (plain MLP)
@@ -131,10 +155,12 @@ class ModelConfig:
 
     @property
     def num_periods(self) -> int:
-        assert self.num_layers % self.period == 0, (
-            f"{self.name}: num_layers={self.num_layers} not divisible by "
-            f"block_pattern period={self.period}")
-        return self.num_layers // self.period
+        n = self.num_layers - self.leading_dense_layers
+        assert n % self.period == 0, (
+            f"{self.name}: {n} layers after the {self.leading_dense_layers} "
+            f"leading dense ones not divisible by block_pattern "
+            f"period={self.period}")
+        return n // self.period
 
     def replace(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)
@@ -150,7 +176,9 @@ class ModelConfig:
             if self.attention_type == "mla":
                 m = self.mla
                 qdim = n_q * (m.qk_nope_head_dim + m.qk_rope_head_dim)
-                return (d * m.q_lora_rank + m.q_lora_rank * qdim
+                q = (d * qdim if m.q_lora_rank is None
+                     else d * m.q_lora_rank + m.q_lora_rank * qdim)
+                return (q
                         + d * (m.kv_lora_rank + m.qk_rope_head_dim)
                         + m.kv_lora_rank * n_q * (m.qk_nope_head_dim + m.v_head_dim)
                         + n_q * m.v_head_dim * d)
@@ -164,7 +192,7 @@ class ModelConfig:
             m = self.moe
             dff = m.d_expert or self.d_ff
             n_e = (m.top_k if active else m.num_experts) + m.num_shared
-            return n_e * mlp_params(dff) + d * m.num_experts
+            return n_e * mlp_params(dff) + d * (m.router_experts or m.num_experts)
 
         def mamba_params() -> int:
             mc = self.mamba
@@ -195,6 +223,7 @@ class ModelConfig:
             elif mlp == "glu":
                 per_period += mlp_params(int(self.xlstm.slstm_ffn_factor * d)) if self.xlstm else mlp_params(self.d_ff)
         total += per_period * self.num_periods
+        total += self.leading_dense_layers * (attn_params() + mlp_params(self.d_ff))
 
         if self.encoder is not None:  # whisper: encoder self-attn + mlp, decoder cross-attn
             enc = self.encoder.num_layers * (attn_params() + mlp_params(self.d_ff))

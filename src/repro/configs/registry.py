@@ -17,6 +17,7 @@ _MODULES: Dict[str, str] = {
     "whisper-medium": "whisper_medium",
     "xlstm-125m": "xlstm_125m",
     "qwen2-vl-7b": "qwen2_vl_7b",
+    "deepseek-v2-lite": "deepseek_v2_lite",
 }
 
 
@@ -32,7 +33,7 @@ def get_config(name: str, smoke: bool = False) -> ModelConfig:
 
 
 def iter_cells():
-    """All (arch, shape) cells with applicability flags — 40 total."""
+    """All (arch, shape) cells with applicability flags — 44 total."""
     for arch in list_archs():
         cfg = get_config(arch)
         for shape in SHAPES.values():

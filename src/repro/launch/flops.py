@@ -18,7 +18,7 @@ def _matmul_params(cfg: ModelConfig) -> int:
 
 def _attn_layers(cfg: ModelConfig) -> int:
     per = sum(1 for m, _ in cfg.block_pattern if m == "attn")
-    return per * cfg.num_periods
+    return per * cfg.num_periods + cfg.leading_dense_layers
 
 
 def _mixer_layers(cfg: ModelConfig, kind: str) -> int:

@@ -49,10 +49,15 @@ def prefill(cfg: ModelConfig, params: Params, batch: Dict[str, jax.Array],
 
 
 def decode_step(cfg: ModelConfig, params: Params, cache: Params,
-                token: jax.Array, pos: jax.Array, ctx: ShardCtx = NULL_CTX):
+                token: jax.Array, pos: jax.Array, ctx: ShardCtx = NULL_CTX,
+                counters: bool = False):
+    """(logits, cache), and the step's MoE counters after them where
+    ``counters`` is set and the model has expert-parallel layers
+    (``lm.decode_step``)."""
     if is_encdec(cfg):
         return whisper.decode_step(cfg, params, cache, token, pos, ctx)
-    return lm.decode_step(cfg, params, cache, token, pos, ctx=ctx)
+    return lm.decode_step(cfg, params, cache, token, pos, ctx=ctx,
+                          counters=counters)
 
 
 def cache_sds(cfg: ModelConfig, batch: int, max_len: int):

@@ -16,7 +16,7 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.distributed.sharding import ShardCtx, NULL_CTX
 from repro.models.params import ParamDef, dense
-from repro.models.layers import apply_rotary, rms_norm
+from repro.models.layers import apply_rotary, rms_norm, yarn_softmax_scale
 
 Params = Dict[str, Any]
 NEG_INF = -1e30
@@ -54,10 +54,14 @@ def _gqa_defs(cfg: ModelConfig, cross: bool = False) -> Params:
 def _mla_defs(cfg: ModelConfig) -> Params:
     m, d, nq = cfg.mla, cfg.d_model, cfg.num_heads
     qd = m.qk_nope_head_dim + m.qk_rope_head_dim
+    if m.q_lora_rank is None:
+        q = {"wq": dense(d, nq * qd, ("embed", "heads"))}
+    else:
+        q = {"wq_a": dense(d, m.q_lora_rank, ("embed", "lora")),
+             "q_norm": ParamDef((m.q_lora_rank,), (None,), "ones"),
+             "wq_b": dense(m.q_lora_rank, nq * qd, ("lora", "heads"))}
     return {
-        "wq_a": dense(d, m.q_lora_rank, ("embed", "lora")),
-        "q_norm": ParamDef((m.q_lora_rank,), (None,), "ones"),
-        "wq_b": dense(m.q_lora_rank, nq * qd, ("lora", "heads")),
+        **q,
         "wkv_a": dense(d, m.kv_lora_rank + m.qk_rope_head_dim, ("embed", None)),
         "kv_norm": ParamDef((m.kv_lora_rank,), (None,), "ones"),
         "wkv_b": dense(m.kv_lora_rank,
@@ -249,6 +253,12 @@ def mla_apply(cfg: ModelConfig, p: Params, x: jax.Array, *,
               mode: str, ctx: ShardCtx = NULL_CTX,
               cache: Optional[Params] = None, pos: Optional[jax.Array] = None,
               ) -> Tuple[jax.Array, Optional[Params]]:
+    with jax.named_scope("truffle.mla"):
+        return _mla(cfg, p, x, rope=rope, mode=mode, ctx=ctx, cache=cache, pos=pos)
+
+
+def _mla(cfg: ModelConfig, p: Params, x: jax.Array, *, rope, mode: str,
+         ctx: ShardCtx, cache: Optional[Params], pos: Optional[jax.Array]):
     m = cfg.mla
     dt = x.dtype
     B, S, _ = x.shape
@@ -256,8 +266,12 @@ def mla_apply(cfg: ModelConfig, p: Params, x: jax.Array, *,
     nope, rdim, vd = m.qk_nope_head_dim, m.qk_rope_head_dim, m.v_head_dim
     cos, sin = rope
 
-    ql = rms_norm(x @ p["wq_a"].astype(dt), p["q_norm"], cfg.norm_eps)
-    q = (ql @ p["wq_b"].astype(dt)).reshape(B, S, nq, nope + rdim)
+    if m.q_lora_rank is None:
+        q = x @ p["wq"].astype(dt)
+    else:
+        ql = rms_norm(x @ p["wq_a"].astype(dt), p["q_norm"], cfg.norm_eps)
+        q = ql @ p["wq_b"].astype(dt)
+    q = q.reshape(B, S, nq, nope + rdim)
     q_nope, q_pe = q[..., :nope], q[..., nope:]
     q_pe = apply_rotary(q_pe, cos, sin)
 
@@ -266,6 +280,8 @@ def mla_apply(cfg: ModelConfig, p: Params, x: jax.Array, *,
     k_pe = apply_rotary(kv_a[..., m.kv_lora_rank:][:, :, None, :], cos, sin)[:, :, 0, :]
 
     scale = (nope + rdim) ** -0.5
+    if cfg.yarn is not None:
+        scale *= yarn_softmax_scale(cfg.yarn)
     wkv_b = p["wkv_b"].astype(dt).reshape(m.kv_lora_rank, nq, nope + vd)
     w_uk, w_uv = wkv_b[..., :nope], wkv_b[..., nope:]
 

@@ -1,12 +1,13 @@
 """Shared layers: norms, RoPE (incl. partial + M-RoPE), MLPs, embeddings."""
 from __future__ import annotations
 
+import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from repro.configs.base import ModelConfig
+from repro.configs.base import ModelConfig, YarnConfig
 from repro.distributed.sharding import ShardCtx, NULL_CTX
 from repro.models.params import ParamDef, dense
 
@@ -54,6 +55,43 @@ def rope_tables(positions: jax.Array, rot_dim: int, theta: float) -> Tuple[jax.A
     freq = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
     ang = positions[..., None].astype(jnp.float32) * freq
     return jnp.cos(ang), jnp.sin(ang)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_softmax_scale(y: YarnConfig) -> float:
+    """What YaRN multiplies the attention's 1/sqrt(d) by (DeepSeek-V2)."""
+    return yarn_mscale(y.factor, y.mscale_all_dim) ** 2 if y.mscale_all_dim else 1.0
+
+
+def yarn_ramp(y: YarnConfig, rot_dim: int, theta: float) -> Tuple[float, float]:
+    """The frequency dimensions between which YaRN blends the interpolated
+    frequencies into the original ones: below ``low`` a dimension keeps its
+    frequency, above ``high`` it is divided by ``factor``."""
+    def dim(rotations):
+        return (rot_dim * math.log(y.original_max_position_embeddings
+                                   / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+    low = max(math.floor(dim(y.beta_fast)), 0)
+    high = min(math.ceil(dim(y.beta_slow)), rot_dim - 1)
+    return low, high
+
+
+def yarn_tables(positions: jax.Array, rot_dim: int, theta: float,
+                y: YarnConfig) -> Tuple[jax.Array, jax.Array]:
+    """cos/sin tables of YaRN rope (``DeepseekV2YarnRotaryEmbedding``), at
+    every position, laid out as ``rope_tables``'."""
+    half = rot_dim // 2
+    freq = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
+    low, high = yarn_ramp(y, rot_dim, theta)
+    ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - low)
+                    / (high - low if high != low else 0.001), 0.0, 1.0)
+    freq = freq / y.factor * ramp + freq * (1.0 - ramp)
+    ang = positions[..., None].astype(jnp.float32) * freq
+    m = yarn_mscale(y.factor, y.mscale) / yarn_mscale(y.factor, y.mscale_all_dim)
+    return jnp.cos(ang) * m, jnp.sin(ang) * m
 
 
 def mrope_tables(positions: jax.Array, sections: Tuple[int, ...], rot_dim: int,

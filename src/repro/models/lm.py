@@ -65,13 +65,21 @@ def block_defs(cfg: ModelConfig, mixer: str, mlp: str) -> Params:
     return out
 
 
+# Parameter and cache key of the leading dense layers, beside ``blocks``.
+LEAD = "lead"
+
+
 def lm_defs(cfg: ModelConfig) -> Params:
     blocks = {}
     for i, (mixer, mlp) in enumerate(cfg.block_pattern):
         blocks[f"pos{i}"] = stack_defs(block_defs(cfg, mixer, mlp),
                                        cfg.num_periods, "layers")
-    return {"embed": L.embed_defs(cfg), "blocks": blocks,
-            "final_norm": L.norm_defs(cfg)}
+    out = {"embed": L.embed_defs(cfg), "blocks": blocks,
+           "final_norm": L.norm_defs(cfg)}
+    if cfg.leading_dense_layers:
+        out[LEAD] = stack_defs(block_defs(cfg, "attn", "mlp"),
+                               cfg.leading_dense_layers, "layers")
+    return out
 
 
 def init(cfg: ModelConfig, key: jax.Array) -> Params:
@@ -109,17 +117,20 @@ def cache_spec(cfg: ModelConfig, batch: int, max_len: int):
     shapes: Params = {}
     dtypes: Params = {}
     axes: Params = {}
-    np_ = cfg.num_periods
-    for i, (mixer, _) in enumerate(cfg.block_pattern):
+    stacks = [(f"pos{i}", mixer, cfg.num_periods)
+              for i, (mixer, _) in enumerate(cfg.block_pattern)]
+    if cfg.leading_dense_layers:
+        stacks.append((LEAD, "attn", cfg.leading_dense_layers))
+    for key, mixer, n in stacks:
         sh = _pos_cache_shapes(cfg, mixer, batch, max_len)
-        shapes[f"pos{i}"] = {k: (np_,) + tuple(v) for k, v in sh.items()}
-        dtypes[f"pos{i}"] = {k: _cache_dtype(cfg, mixer, k) for k in sh}
+        shapes[key] = {k: (n,) + tuple(v) for k, v in sh.items()}
+        dtypes[key] = {k: _cache_dtype(cfg, mixer, k) for k in sh}
         if mixer == "attn":
             ax = attn.cache_axes(cfg, stacked=True)
         else:
             ax = {k: ("layers", "cache_batch") + (None,) * (len(v) - 1)
                   for k, v in sh.items()}
-        axes[f"pos{i}"] = ax
+        axes[key] = ax
     return shapes, dtypes, axes
 
 
@@ -150,11 +161,13 @@ def _rope_for(cfg: ModelConfig, positions: jax.Array,
         return None  # e.g. Jamba: attention layers carry no positional encoding
     if cfg.vision is not None and mrope_positions is not None:
         return L.mrope_tables(mrope_positions, cfg.vision.mrope_sections, rot, cfg.rope_theta)
+    if cfg.yarn is not None:
+        return L.yarn_tables(positions, rot, cfg.rope_theta, cfg.yarn)
     return L.rope_tables(positions, rot, cfg.rope_theta)
 
 
 def _apply_block(cfg: ModelConfig, p: Params, x: jax.Array, mixer: str, mlp: str,
-                 *, rope, mode: str, ctx: ShardCtx, cache, pos):
+                 *, rope, mode: str, ctx: ShardCtx, cache, pos, experts=None):
     h = L.apply_norm(cfg, p["mixer_norm"], x)
     if mixer == "attn":
         fn = attn.mla_apply if cfg.attention_type == "mla" else attn.gqa_apply
@@ -173,7 +186,8 @@ def _apply_block(cfg: ModelConfig, p: Params, x: jax.Array, mixer: str, mlp: str
     if mlp != "none":
         h = L.apply_norm(cfg, p["mlp_norm"], x)
         if mlp == "moe":
-            y, aux = moe_mod.moe_apply(cfg, p["mlp"], h, ctx)
+            y, aux = moe_mod.moe_apply(cfg, p["mlp"], h, ctx, mode=mode,
+                                       experts=experts)
         else:
             y = L.apply_mlp(cfg, p["mlp"], h, ctx)
         x = x + y
@@ -206,14 +220,36 @@ def forward(cfg: ModelConfig, params: Params, tokens: jax.Array, *,
     has_cache = cache is not None
     want_cache = mode in ("prefill", "decode")
 
+    lead_cache = None
+    if cfg.leading_dense_layers:
+        def lead_body(x, per_layer):
+            p, c = per_layer
+            x, nc, _ = _apply_block(cfg, p, x, "attn", "mlp", rope=rope, mode=mode,
+                                    ctx=ctx, cache=c, pos=pos)
+            return x, (nc if want_cache else None)
+
+        if has_cache:
+            cache = dict(cache)
+            xs_lead = cache.pop(LEAD)
+        else:
+            xs_lead = jax.tree.map(lambda _: None, params[LEAD])
+        x, lead_cache = jax.lax.scan(lead_body, x, (params[LEAD], xs_lead))
+
+    # An expert-parallel share reads every layer's held experts as one stack
+    # (``moe._dropless``), so its layer index rides along the scan.
+    stacks = params["blocks"] if moe_mod.serves_share(cfg, mode) else None
+
     def period_body(x, per_layer):
-        p_by_pos, c_by_pos = per_layer
+        p_by_pos, c_by_pos, layer = per_layer
         new_caches = {}
         aux_sum = None
         for i, (mixer, mlp) in enumerate(cfg.block_pattern):
             c_i = c_by_pos[f"pos{i}"] if has_cache else None
+            experts = (stacks[f"pos{i}"]["mlp"], layer) \
+                if stacks is not None and mlp == "moe" else None
             x, nc, aux = _apply_block(cfg, p_by_pos[f"pos{i}"], x, mixer, mlp,
-                                      rope=rope, mode=mode, ctx=ctx, cache=c_i, pos=pos)
+                                      rope=rope, mode=mode, ctx=ctx, cache=c_i, pos=pos,
+                                      experts=experts)
             if want_cache:
                 new_caches[f"pos{i}"] = nc
             if aux:
@@ -227,7 +263,8 @@ def forward(cfg: ModelConfig, params: Params, tokens: jax.Array, *,
             policy = (jax.checkpoint_policies.nothing_saveable if cfg.remat == "full"
                       else jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims)
             body = jax.checkpoint(period_body, policy=policy)
-        x, (new_cache, auxs) = jax.lax.scan(body, x, (params["blocks"], xs_cache))
+        layers = jnp.arange(cfg.num_periods) if stacks is not None else None
+        x, (new_cache, auxs) = jax.lax.scan(body, x, (params["blocks"], xs_cache, layers))
     else:
         body = period_body
         if mode == "train" and cfg.remat != "none":
@@ -238,7 +275,7 @@ def forward(cfg: ModelConfig, params: Params, tokens: jax.Array, *,
         for li in range(cfg.num_periods):
             sl = jax.tree.map(lambda a: a[li], params["blocks"])
             cl = jax.tree.map(lambda a: a[li], cache) if has_cache else None
-            x, (nc, aux) = body(x, (sl, cl))
+            x, (nc, aux) = body(x, (sl, cl, li if stacks is not None else None))
             if want_cache:
                 new_cache[li] = nc
             auxs.append(aux)
@@ -246,6 +283,8 @@ def forward(cfg: ModelConfig, params: Params, tokens: jax.Array, *,
             new_cache = jax.tree.map(lambda *xs_: jnp.stack(xs_), *new_cache.values())
         auxs = jax.tree.map(lambda *xs_: jnp.stack(xs_), *auxs) if auxs and auxs[0] else {}
 
+    if want_cache and lead_cache is not None:
+        new_cache = {**new_cache, LEAD: lead_cache}
     x = L.apply_norm(cfg, params["final_norm"], x)
     aux_out = {k: jnp.sum(v) for k, v in auxs.items()} if auxs else {}
     return x, (new_cache if want_cache else None), aux_out
@@ -319,7 +358,14 @@ def prefill(cfg: ModelConfig, params: Params, tokens: jax.Array, *,
 
 
 def decode_step(cfg: ModelConfig, params: Params, cache: Params,
-                token: jax.Array, pos: jax.Array, *, ctx: ShardCtx = NULL_CTX):
-    """token [B,1]; pos scalar int32 (index where this token is written)."""
-    h, new_cache, _ = forward(cfg, params, token, mode="decode", ctx=ctx, cache=cache, pos=pos)
-    return logits_at_last(cfg, params, h, ctx), new_cache
+                token: jax.Array, pos: jax.Array, *, ctx: ShardCtx = NULL_CTX,
+                counters: bool = False):
+    """token [B,1]; pos scalar int32 (index where this token is written).
+    With ``counters``, a model with expert-parallel layers also returns their
+    ``moe.MOE_COUNTERS`` for the step, summed over its layers, as int32 [3]."""
+    h, new_cache, aux = forward(cfg, params, token, mode="decode", ctx=ctx,
+                                cache=cache, pos=pos)
+    logits = logits_at_last(cfg, params, h, ctx)
+    if counters and moe_mod.MOE_COUNTERS[0] in aux:
+        return logits, new_cache, jnp.stack([aux[k] for k in moe_mod.MOE_COUNTERS])
+    return logits, new_cache

@@ -48,6 +48,13 @@ class EngineStats:
     decode_host_s: float = 0.0
     decode_steps: int = 0
     tokens_out: int = 0
+    # Expert-parallel MoE layers in decode steps (``moe.MOE_COUNTERS``),
+    # summed over steps and layers: top-k assignments served by held experts,
+    # each step's and layer's busiest held expert's tokens, and dropped
+    # assignments. Summed on the device, read once per ``step_batch``.
+    moe_routed: int = 0
+    moe_busiest: int = 0
+    moe_dropped: int = 0
 
 
 @contextmanager
@@ -77,6 +84,7 @@ class ServeEngine:
         self.stats = EngineStats()
         self.prompt_len: Optional[int] = None   # prefill length compiled for
         self.last_state: Optional[DecodeState] = None
+        self._moe_counts: Optional[jax.Array] = None  # on the device, unread
 
     # ------------------------------------------------------------- lifecycle
     def warmup(self, prompt_len: int) -> None:
@@ -100,7 +108,7 @@ class ServeEngine:
             {"tokens": jax.ShapeDtypeStruct((B, L), jnp.int32)}).compile()
         cache_sds = api.cache_sds(cfg, B, self.max_len)
         self._decode = jax.jit(
-            lambda p, c, t, q: api.decode_step(cfg, p, c, t, q)).lower(
+            lambda p, c, t, q: api.decode_step(cfg, p, c, t, q, counters=True)).lower(
                 jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
                              self.params),
                 cache_sds,
@@ -126,6 +134,7 @@ class ServeEngine:
                 self._queue = self._queue[self.max_batch:]
             if not batch:
                 return []
+            self.last_state = None     # the previous batch's cache, freed
             B = self.max_batch
             plen = max(len(r.prompt) for r in batch)
             toks = np.zeros((B, plen), np.int32)
@@ -163,6 +172,7 @@ class ServeEngine:
             self.stats.decode_s += time.monotonic() - t0
             self.stats.decode_host_s += host_s
             self.stats.decode_steps += max_new - 1
+            self._read_moe_counts()
             self.last_state = DecodeState(cache, token, pos)
 
             gen = np.asarray(results).T                       # [B, max_new]
@@ -175,8 +185,25 @@ class ServeEngine:
         """One compiled decode step: token [B, 1] written at cache slot
         ``pos`` -> (logits [B, 1, V], grown cache)."""
         with jax.profiler.TraceAnnotation("truffle.engine.decode"):
-            return self._decode(self.params, cache, token,
-                                jnp.asarray(pos, jnp.int32))
+            out = self._decode(self.params, cache, token,
+                               jnp.asarray(pos, jnp.int32))
+        if len(out) == 3:                  # expert-parallel MoE: its counters
+            if self._moe_counts is None:   # the add compiles in the warm-up
+                self._moe_counts = jnp.zeros_like(out[2])
+            self._moe_counts = self._moe_counts + out[2]
+        return out[:2]
+
+    def _read_moe_counts(self) -> None:
+        """Add the decode steps' MoE counters, summed on the device one
+        step at a time (one shape, so nothing compiles per batch length), to
+        the stats in one read."""
+        if self._moe_counts is not None:
+            total = np.asarray(self._moe_counts)
+            self._moe_counts = None
+            routed, busiest, dropped = (int(v) for v in total)
+            self.stats.moe_routed += routed
+            self.stats.moe_busiest += busiest
+            self.stats.moe_dropped += dropped
 
     def _grow_cache(self, cache, plen: int):
         """Pad prefill cache out to max_len decode slots."""

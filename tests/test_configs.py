@@ -1,5 +1,5 @@
-"""Registry + config integrity: all 10 assigned archs, param counts vs
-published sizes, shape applicability grid (40 cells)."""
+"""Registry + config integrity: all 11 archs, param counts vs published
+sizes, shape applicability grid (44 cells)."""
 import pytest
 
 from repro.configs.base import SHAPES, shape_applicable
@@ -16,11 +16,12 @@ PUBLISHED_B = {  # (total, active) billions from the papers / model cards
     "whisper-medium": (0.77, 0.77),
     "xlstm-125m": (0.16, 0.16),
     "qwen2-vl-7b": (7.6, 7.6),
+    "deepseek-v2-lite": (15.7, 2.4),
 }
 
 
 def test_all_archs_present():
-    assert len(list_archs()) == 10
+    assert len(list_archs()) == 11
     assert set(list_archs()) == set(PUBLISHED_B)
 
 
@@ -41,13 +42,13 @@ def test_smoke_config_valid(arch):
 
 def test_cell_grid_is_40():
     cells = list(iter_cells())
-    assert len(cells) == 40
+    assert len(cells) == 44
     runnable = [c for c in cells if c[2]]
     # long_500k only for the two sub-quadratic archs
     skipped = [c for c in cells if not c[2]]
-    assert len(skipped) == 8
+    assert len(skipped) == 9
     assert all(s[1].name == "long_500k" for s in skipped)
-    assert len(runnable) == 32
+    assert len(runnable) == 35
 
 
 def test_long_context_applicability():

@@ -14,9 +14,10 @@ from repro.configs.base import MoEConfig, ShapeConfig
 from repro.configs.registry import get_config
 from repro.models import api, lm, layers, moe
 
-# one representative per cache family: GQA, MLA, hybrid(mamba), xLSTM, enc-dec
+# one representative per cache family: GQA, MLA, hybrid(mamba), xLSTM, enc-dec,
+# and MLA behind a leading dense layer with expert-parallel MoE
 DECODE_FAMILIES = ["glm4-9b", "minicpm3-4b", "jamba-v0.1-52b", "xlstm-125m",
-                   "whisper-medium"]
+                   "whisper-medium", "deepseek-v2-lite"]
 
 
 def _setup(arch):

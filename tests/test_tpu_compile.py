@@ -1,7 +1,10 @@
 """Compiles for a described TPU v5e, with no chip attached: the Pallas
-kernels at qwen3-4b widths, and qwen3-4b's full-width decode step with the
-weights in the serving dtype. Nothing runs; the TPU compiler refuses what
+kernels at qwen3-4b widths, qwen3-4b's full-width decode step with the
+weights in the serving dtype, and DeepSeek-V2-Lite's expert-parallel share
+(the grouped matmul of megablox over the stack of every layer's experts). Nothing runs; the TPU compiler refuses what
 would not lower or not fit the chip's memory, as it would on the chip."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -87,3 +90,26 @@ def test_full_width_decode_step_fits_one_chip(one_chip):
         _sds((batch, 1), jnp.int32, one_chip), _sds((), jnp.int32, one_chip))
     args = compiled.memory_analysis().argument_size_in_bytes
     assert args < HBM_LIMIT_BYTES, args
+
+
+@pytest.mark.parametrize("tokens", [128, 8192])
+def test_expert_share_grouped_matmul_compiles(one_chip, tokens):
+    """One chip's share of a DeepSeek-V2-Lite MoE layer (16 of 64 experts)
+    at a decode step's 128 tokens and a prefill block's 8192, reading its
+    experts from the stack of all 26 layers: the TPU kernel lowers (not its
+    interpreted form) and fits the kernel's VMEM at the chosen tiles."""
+    from repro.models import moe
+    from repro.models.params import abstract_params
+
+    base = serving_config("deepseek-v2-lite", smoke=False)
+    cfg = base.replace(moe=dataclasses.replace(base.moe, num_experts=16))
+    p = jax.tree.map(lambda s: _sds(s.shape, s.dtype, one_chip),
+                     abstract_params(moe.moe_defs(cfg), cfg.param_dtype))
+    stack = {n: _sds((26,) + p[n].shape, p[n].dtype, one_chip)
+             for n in moe.EXPERT_WEIGHTS}
+    x = _sds((tokens, 1, cfg.d_model), jnp.bfloat16, one_chip)
+    compiled = _compile(
+        lambda p, s, x: moe.moe_apply(cfg, p, x, mode="decode",
+                                      experts=(s, jnp.int32(25)))[0],
+        p, stack, x)
+    assert "tpu_custom_call" in compiled.as_text()
