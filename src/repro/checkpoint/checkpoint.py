@@ -1,6 +1,10 @@
 """Sharded checkpointing: save/restore pytrees as npz shards + manifest,
 async (background-thread) saves, rotation, and CSP-streamed restore; and the
 CSP payload codec (``serialize``/``deserialize``), a raw typed wire format.
+A tree whose leaves all live on one device is packed into that format on
+the device and copied to the host once; ``serialize`` returns a bytes-like
+object (``bytes`` or a ``memoryview``), never a copy made only to change
+its type.
 
 Fault-tolerance contract (exercised by launch/train.py --inject-failure):
   * saves are atomic (tmp dir + rename);
@@ -23,6 +27,7 @@ from typing import Any, Dict, Iterator, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 PyTree = Any
 _SEP = "::"
@@ -37,6 +42,7 @@ _NPZ_SAVABLE = {"float64", "float32", "float16", "int64", "int32", "int16",
 _MAGIC = b"TRFCSP01"
 _PREFIX = struct.Struct("<8sQ")
 _ALIGN = 64
+_UINT = {1: np.uint8, 2: np.uint16, 4: np.uint32}
 
 
 def _aligned(n: int) -> int:
@@ -82,28 +88,92 @@ def _unflatten_into(like: PyTree, flat: Dict[str, np.ndarray]) -> PyTree:
     return jax.tree_util.tree_unflatten(paths[1], leaves)
 
 
-def serialize(tree: PyTree) -> bytes:
+def _head(leaves) -> bytes:
+    """Magic, header length, JSON header and padding to ``_ALIGN`` for
+    ``[(key, dtype, shape, nbytes), ...]``: the payload up to its data."""
+    entries, offset = [], 0
+    for key, dtype, shape, nbytes in leaves:
+        entries.append([key, str(dtype), list(shape), offset])
+        offset += _aligned(nbytes)
+    header = json.dumps({"leaves": entries}).encode()
+    start = _PREFIX.size + len(header)
+    return (_PREFIX.pack(_MAGIC, len(header)) + header
+            + bytes(_aligned(start) - start))
+
+
+def _packable(leaves) -> bool:
+    """Whether the device can pack these leaves: each a ``jax.Array`` of 8-,
+    16- or 32-bit items, all on one and the same device."""
+    devices = set()
+    for x in leaves:
+        if (not isinstance(x, jax.Array)
+                or jax.dtypes.itemsize_bits(x.dtype) not in (8, 16, 32)):
+            return False
+        devices |= x.devices()
+    return len(devices) == 1
+
+
+def _words(x: jax.Array) -> jax.Array:
+    """``x``'s bytes in C order, zero-padded to ``_ALIGN``, as 1-D uint32
+    words, little-endian like the host. Narrower items are paired into a
+    word through the transpose: on the TPU the items of a 32-bit word lie
+    along the second-minor axis, and pairing them along the minor axis
+    would lay each pair out in a tile of its own."""
+    if x.dtype == jnp.bool_:
+        x = x.astype(jnp.uint8)
+    size = x.dtype.itemsize
+    u = lax.bitcast_convert_type(x, _UINT[size]).reshape(-1)
+    if size < 4:
+        rows = -(-u.size // 128)
+        a = jnp.pad(u, (0, rows * 128 - u.size)).reshape(rows, 128)
+        k = 4 // size
+        items = a.T.reshape(128 // k, k, rows).transpose(0, 2, 1)
+        u = lax.bitcast_convert_type(items, np.uint32).T.reshape(-1)
+    n = _aligned(x.nbytes) // 4
+    return jnp.pad(u[:n], (0, n - min(n, u.size)))
+
+
+@jax.jit
+def _pack(head: jax.Array, leaves) -> jax.Array:
+    """The whole payload as one 1-D array of uint32 words: ``head``
+    (``_head``'s bytes), then each leaf's ``_words``. The TPU tiles a 1-D
+    array of 32-bit words in runs of consecutive words, so its copy to the
+    host needs no change of layout, unlike one of 16-bit items."""
+    return jnp.concatenate([head] + [_words(x) for x in leaves])
+
+
+def serialize(tree: PyTree):
     """Whole-tree bytes (CSP payloads, storage uploads), each leaf in the
-    dtype it has: per leaf ``truffle.csp.d2h`` (to host), then one
-    ``truffle.csp.pack``, the only copy of the leaves' bytes. Layout:
-    ``_MAGIC``, the header's length (little-endian uint64), a JSON header
-    ``{"leaves": [[key, dtype, shape, offset], ...]}``, padding to
-    ``_ALIGN``, then the leaves, each at ``offset`` from that point and
-    padded to ``_ALIGN``."""
+    dtype it has, as a bytes-like object. Layout: ``_MAGIC``, the header's
+    length (little-endian uint64), a JSON header ``{"leaves": [[key, dtype,
+    shape, offset], ...]}``, padding to ``_ALIGN``, then the leaves, each at
+    ``offset`` from that point and padded to ``_ALIGN``.
+
+    Where every leaf is a ``jax.Array`` on one device (``_packable``), the
+    device packs the payload into 32-bit words (``_pack``, under
+    ``truffle.csp.pack`` and ``.device_pack``) and one
+    ``truffle.csp.d2h`` copies it to the host, which returns a read-only
+    ``memoryview`` over that copy. Any other tree is copied to the host leaf
+    by leaf (a ``truffle.csp.d2h`` each), then joined into ``bytes`` under
+    ``truffle.csp.pack``, the only copy of the leaves' bytes."""
     with jax.profiler.TraceAnnotation("truffle.csp.serialize"):
-        leaves, raw, offset = [], [], 0
-        for key, v in _host_leaves(tree):
-            leaves.append([key, str(v.dtype), list(v.shape), offset])
-            # C-order bytes (a copy only if v is strided); bf16 exports no buffer
-            raw.append(v.reshape(-1).view(np.uint8))
-            offset += _aligned(v.nbytes)
+        flat = [(_key(path), leaf) for path, leaf
+                in jax.tree_util.tree_flatten_with_path(tree)[0]]
+        if flat and _packable([leaf for _, leaf in flat]):
+            with jax.profiler.TraceAnnotation("truffle.csp.pack"):
+                head = _head([(k, x.dtype, x.shape, x.nbytes) for k, x in flat])
+                with jax.profiler.TraceAnnotation("truffle.csp.device_pack"):
+                    words = _pack(np.frombuffer(head, np.uint32),
+                                  [x for _, x in flat])
+            with jax.profiler.TraceAnnotation("truffle.csp.d2h"):
+                return memoryview(np.asarray(words)).cast("B")
+        raw = list(_host_leaves(tree))
         with jax.profiler.TraceAnnotation("truffle.csp.pack"):
-            header = json.dumps({"leaves": leaves}).encode()
-            start = _PREFIX.size + len(header)
-            parts = [_PREFIX.pack(_MAGIC, len(header)), header,
-                     bytes(_aligned(start) - start)]
-            for r in raw:
-                parts += [r, bytes(_aligned(r.nbytes) - r.nbytes)]
+            parts = [_head([(k, v.dtype, v.shape, v.nbytes) for k, v in raw])]
+            for _, v in raw:
+                # C-order bytes (a copy only if v is strided); bf16 exports no buffer
+                parts += [v.reshape(-1).view(np.uint8),
+                          bytes(_aligned(v.nbytes) - v.nbytes)]
             return b"".join(parts)
 
 

@@ -11,6 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.checkpoint import checkpoint
 from repro.checkpoint.checkpoint import (CheckpointManager, deserialize,
                                          serialize)
 
@@ -73,9 +74,9 @@ def test_sharded_save(tmp_path):
 def test_serialize_bytes_roundtrip():
     s = _state()
     data = serialize(s)
-    assert isinstance(data, bytes) and len(data) > 100
-    restored = deserialize(data, _state(1))
-    _assert_tree_equal(s, restored)
+    assert memoryview(data).nbytes == len(data) > 100      # bytes-like
+    for payload in (data, bytes(data)):
+        _assert_tree_equal(s, deserialize(payload, _state(1)))
 
 
 def test_restore_missing_raises(tmp_path):
@@ -96,19 +97,41 @@ LEAVES = {
     "empty": jnp.zeros((0, 4), jnp.bfloat16),
     "transposed": np.arange(24, dtype=np.float32).reshape(4, 6).T,
     "scalar": 7,
+    "int4": jnp.arange(-3, 4, dtype=jnp.int4),
 }
+HOST_LEAVES = ["transposed", "scalar", "int4"]    # the device cannot pack these
+DEVICE_PACK = "truffle.csp.device_pack"
 
 
 def _bits(x):
     return np.ascontiguousarray(np.asarray(x)).view(np.uint8).tobytes()
 
 
+def _with_neighbours(leaf):
+    """``leaf`` with an odd-sized neighbour on each side, so every offset is
+    exercised."""
+    return {"a": jnp.arange(3, dtype=jnp.int8), "leaf": leaf,
+            "z": jnp.ones((5,), jnp.bfloat16)}
+
+
+@pytest.fixture
+def spans(monkeypatch):
+    """Names of the profiler annotations opened, in order."""
+    names = []
+    real = jax.profiler.TraceAnnotation
+
+    @contextlib.contextmanager
+    def annotation(name, **kw):
+        names.append(name)
+        with real(name, **kw):
+            yield
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", annotation)
+    return names
+
+
 @pytest.mark.parametrize("name", list(LEAVES))
 def test_wire_roundtrip_is_bit_exact(name):
-    leaf = LEAVES[name]
-    # an odd-sized neighbour on each side, so every offset is exercised
-    tree = {"a": jnp.arange(3, dtype=jnp.int8), "leaf": leaf,
-            "z": jnp.ones((5,), jnp.bfloat16)}
+    tree = _with_neighbours(LEAVES[name])
     back = deserialize(serialize(tree), like=tree)
     for key, want in tree.items():
         got = back[key]
@@ -118,7 +141,7 @@ def test_wire_roundtrip_is_bit_exact(name):
 
 
 def test_payload_is_header_plus_aligned_leaves():
-    tree = {k: v for k, v in LEAVES.items() if k != "scalar"}
+    tree = {k: v for k, v in LEAVES.items() if k not in ("scalar", "int4")}
     payload = serialize(tree)
     magic, n = _HEAD.unpack_from(payload)
     aligned = lambda b: -(-b // 64) * 64
@@ -154,23 +177,47 @@ def test_foreign_payload_raises_value_error(payload):
         deserialize(payload, like={"a": np.ones(3, np.float32)})
 
 
-def test_dtype_mismatch_casts_under_one_narrow(monkeypatch):
-    names = []
-    real = jax.profiler.TraceAnnotation
-
-    @contextlib.contextmanager
-    def annotation(name, **kw):
-        names.append(name)
-        with real(name, **kw):
-            yield
-    monkeypatch.setattr(jax.profiler, "TraceAnnotation", annotation)
+def test_dtype_mismatch_casts_under_one_narrow(spans):
     stored = {"k": LEAVES["f32"], "n": LEAVES["int32"]}
     payload = serialize(stored)
-    del names[:]
+    del spans[:]
     like = {"k": jnp.zeros((2, 3, 4), jnp.bfloat16), "n": LEAVES["int32"]}
     back = deserialize(payload, like=like)
-    assert names == ["truffle.csp.deserialize", "truffle.csp.unpack",
+    assert spans == ["truffle.csp.deserialize", "truffle.csp.unpack",
                      "truffle.csp.narrow"]
     assert back["k"].dtype == jnp.bfloat16
     assert _bits(back["k"]) == _bits(np.asarray(stored["k"]).astype(jnp.bfloat16))
     assert _bits(back["n"]) == _bits(stored["n"])
+
+
+@pytest.mark.parametrize("name", [k for k in LEAVES if k not in HOST_LEAVES])
+def test_device_pack_equals_host_pack(name, spans):
+    tree = _with_neighbours(LEAVES[name])
+    payload = serialize(tree)
+    assert spans.count(DEVICE_PACK) == 1
+    del spans[:]
+    host = serialize(jax.tree.map(np.asarray, tree))
+    assert DEVICE_PACK not in spans
+    assert bytes(payload) == bytes(host)
+
+
+@pytest.mark.parametrize("name", HOST_LEAVES)
+def test_a_host_leaf_takes_the_host_path(name, spans):
+    tree = _with_neighbours(LEAVES[name])
+    payload = serialize(tree)
+    assert DEVICE_PACK not in spans
+    assert spans.count("truffle.csp.d2h") == len(tree)
+    back = deserialize(payload, like=tree)
+    assert _bits(back["leaf"]) == _bits(tree["leaf"])
+
+
+def test_pack_program_compiles_once_per_shape():
+    first = {"p": jnp.ones((13, 7), jnp.float32), "q": jnp.arange(29, dtype=jnp.int8)}
+    second = {"p": first["p"] * 2, "q": first["q"] + 1}
+    n = checkpoint._pack._cache_size()
+    payloads = [bytes(serialize(t)) for t in (first, second)]
+    assert checkpoint._pack._cache_size() == n + 1
+    assert payloads[0] != payloads[1]
+    for tree, payload in zip((first, second), payloads):
+        back = deserialize(payload, like=tree)
+        assert all(_bits(back[k]) == _bits(v) for k, v in tree.items())

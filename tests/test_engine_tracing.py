@@ -112,10 +112,9 @@ def test_serialize_spans_and_payload(spans):
     tree = _tree()
     payload = serialize(tree)
     assert spans == ["truffle.csp.serialize",
-                     "truffle.csp.d2h",                           # k, bf16
-                     "truffle.csp.d2h",                           # n, int32
-                     "truffle.csp.d2h",                           # v, bf16
-                     "truffle.csp.pack"]
+                     "truffle.csp.pack",
+                     "truffle.csp.device_pack",                   # k, n, v at once
+                     "truffle.csp.d2h"]
     assert payload == _layout([(k, np.asarray(v)) for k, v in tree.items()])
 
     del spans[:]
@@ -143,7 +142,7 @@ def test_spans_land_on_the_profiler_host_plane(engine, tmp_path):
               if e.name.startswith("truffle.")]
     names = [n for n, _, _ in events]
     for name in set(BATCH) | {"truffle.csp.serialize", "truffle.csp.d2h",
-                              "truffle.csp.pack"}:
+                              "truffle.csp.pack", "truffle.csp.device_pack"}:
         assert name in names, name
     (_, b0, b1), = [e for e in events if e[0] == "truffle.engine.batch"]
     steps = [e for e in events if e[0] in STEP]
