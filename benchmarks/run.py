@@ -27,7 +27,6 @@ Prints ``name,us_per_call,derived`` CSV rows (benchmarks/common.emit).
               late-joiner reads, streamed digest MB/s)
   train.*     SDP overlap on a real-compile training cold start
   serve.*     CSP overlap on a prefill->decode KV handoff
-  roofline.*  three-term roofline per dry-run cell (reads experiments/)
 
 Env: BENCH_SCALE (default 0.5) shrinks simulated time; BENCH_FAST=1 runs a
 reduced grid; BENCH_SKIP=ml skips the real-compile ML benches; BENCH_JSON
@@ -61,7 +60,7 @@ def main() -> None:
                             coldstart_sweep, fault_sweep, lifecycle,
                             locality_sweep, model_validation,
                             multitenant_sweep, pipeline_sweep, policy_sweep,
-                            replan_sweep, roofline, streaming_sweep,
+                            replan_sweep, streaming_sweep,
                             substrate_bench, video_analytics)
 
     print("# --- paper figures ---")
@@ -108,12 +107,6 @@ def main() -> None:
         from benchmarks import serve_handoff, train_coldstart
         train_coldstart.run()
         serve_handoff.run()
-
-    print("# --- roofline (from dry-run artifacts) ---")
-    try:
-        roofline.run()
-    except Exception as e:  # noqa: BLE001 — dry-run may not have run yet
-        print(f"# roofline skipped: {e}")
 
     path = _dump_json(t0)
     print(f"# total benchmark wall time: {time.time() - t0:.0f}s")

@@ -9,7 +9,7 @@ periods — compile time stays O(1) in depth.
 from __future__ import annotations
 
 import dataclasses
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 from typing import Optional, Tuple
 
 
@@ -129,21 +129,11 @@ class ModelConfig:
     encoder: Optional[EncoderConfig] = None   # != None -> enc-dec (whisper)
     vision: Optional[VisionConfig] = None     # != None -> VLM (qwen2-vl)
 
-    # --- numerics / performance knobs (hillclimb levers) ---
-    dtype: str = "bfloat16"        # activation/compute dtype
+    # --- numerics ---
+    dtype: str = "bfloat16"        # activation/compute dtype (and the KV cache's)
     param_dtype: str = "float32"   # canonical parameter dtype
     remat: str = "dots"            # none | dots | full  (train-time only)
     loss_chunk: int = 2048         # vocab-loss computed over seq chunks (memory)
-    scan_layers: bool = True       # lax.scan over superblocks (vs unrolled)
-    unroll_scans: bool = False     # unroll inner seq-chunk scans (probe compiles)
-    kv_cache_dtype: str = "model"  # model | int8 (quantized decode cache)
-    attention_impl: str = "xla"    # xla | pallas | pallas_interpret
-
-    # Sub-quadratic? (drives long_500k applicability)
-    @property
-    def subquadratic(self) -> bool:
-        mixers = {m for m, _ in self.block_pattern}
-        return bool(mixers & {"mamba", "mlstm", "slstm"})
 
     @property
     def resolved_head_dim(self) -> int:
@@ -232,27 +222,11 @@ class ModelConfig:
         return int(total)
 
 
-# ---------------------------------------------------------------------------
-# Input shapes assigned to the LM family (all 10 archs share this grid).
-# ---------------------------------------------------------------------------
 @dataclass(frozen=True)
 class ShapeConfig:
+    """A batch shape the train / prefill / decode entry points take
+    (``api.input_specs``)."""
     name: str
     seq_len: int
     global_batch: int
     kind: str                      # train | prefill | decode
-
-
-SHAPES = {
-    "train_4k": ShapeConfig("train_4k", 4_096, 256, "train"),
-    "prefill_32k": ShapeConfig("prefill_32k", 32_768, 32, "prefill"),
-    "decode_32k": ShapeConfig("decode_32k", 32_768, 128, "decode"),
-    "long_500k": ShapeConfig("long_500k", 524_288, 1, "decode"),
-}
-
-
-def shape_applicable(cfg: ModelConfig, shape: ShapeConfig) -> Tuple[bool, str]:
-    """Whether (arch, shape) is a runnable cell; reason if skipped."""
-    if shape.name == "long_500k" and not cfg.subquadratic:
-        return False, "long_500k skipped: pure full-attention arch (quadratic)"
-    return True, ""

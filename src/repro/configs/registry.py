@@ -4,7 +4,7 @@ from __future__ import annotations
 import importlib
 from typing import Dict, List
 
-from repro.configs.base import ModelConfig, SHAPES, ShapeConfig, shape_applicable
+from repro.configs.base import ModelConfig
 
 _MODULES: Dict[str, str] = {
     "glm4-9b": "glm4_9b",
@@ -31,11 +31,3 @@ def get_config(name: str, smoke: bool = False) -> ModelConfig:
     mod = importlib.import_module(f"repro.configs.{_MODULES[name]}")
     return mod.SMOKE if smoke else mod.CONFIG
 
-
-def iter_cells():
-    """All (arch, shape) cells with applicability flags — 44 total."""
-    for arch in list_archs():
-        cfg = get_config(arch)
-        for shape in SHAPES.values():
-            ok, why = shape_applicable(cfg, shape)
-            yield arch, shape, ok, why

@@ -1,14 +1,8 @@
-"""Production meshes. A function (not a module constant) so importing this
+"""Device meshes. Functions (not module constants) so importing this
 module never touches jax device state."""
 from __future__ import annotations
 
 import jax
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
 
 
 def make_mesh(shape, axes):

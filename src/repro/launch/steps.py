@@ -1,21 +1,20 @@
-"""Step builders: train / prefill / decode, with full sharding trees.
+"""The train step, with full sharding trees.
 
-Every builder returns ``(fn, abstract_args)`` where abstract_args is a tree
-of ShapeDtypeStructs *carrying NamedShardings* — ready both for AOT
-``jax.jit(fn).lower(*abstract_args)`` (dry-run) and for real execution with
-concrete arrays laid out the same way.
+``build_train_step`` returns ``(fn, abstract_args)`` where abstract_args is a
+tree of ShapeDtypeStructs *carrying NamedShardings* — ready both for AOT
+``jax.jit(fn).lower(*abstract_args)`` and for real execution with concrete
+arrays laid out the same way.
 """
 from __future__ import annotations
 
-import functools
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig, ShapeConfig
-from repro.distributed.sharding import (ShardCtx, default_rules, dp_axes,
+from repro.distributed.sharding import (ShardCtx, dp_axes,
                                         rules_for_shape, spec_for_axes,
                                         specs_for, shardings_for)
 from repro.distributed.zero import zero1_specs
@@ -152,52 +151,3 @@ def build_train_step(cfg: ModelConfig, mesh: Mesh, shape: ShapeConfig, *,
     batch_raw = api.input_specs(cfg, shape)["batch"]
     batch_sds = _with_shardings(batch_raw, _batch_axes(batch_raw), mesh, rules)
     return train_step, (state_sds, batch_sds)
-
-
-# ---------------------------------------------------------------------------
-# Prefill / decode steps
-# ---------------------------------------------------------------------------
-
-def build_prefill_step(cfg: ModelConfig, mesh: Mesh, shape: ShapeConfig, *,
-                       multi_pod: bool = False,
-                       rules: Optional[Dict[str, Any]] = None):
-    rules = rules or rules_for_shape("prefill", multi_pod=multi_pod)
-    ctx = ShardCtx(mesh, rules)
-
-    def prefill_step(params, batch):
-        return api.prefill(cfg, params, batch, ctx)
-
-    p_sds = abstract_param_tree(cfg, mesh, rules)
-    batch_raw = api.input_specs(cfg, shape)["batch"]
-    batch_sds = _with_shardings(batch_raw, _batch_axes(batch_raw), mesh, rules)
-    return prefill_step, (p_sds, batch_sds)
-
-
-def build_decode_step(cfg: ModelConfig, mesh: Mesh, shape: ShapeConfig, *,
-                      multi_pod: bool = False,
-                      rules: Optional[Dict[str, Any]] = None):
-    rules = rules or rules_for_shape("decode", multi_pod=multi_pod,
-                                     global_batch=shape.global_batch,
-                                     seq_len=shape.seq_len)
-    ctx = ShardCtx(mesh, rules)
-
-    def serve_step(params, cache, token, pos):
-        return api.decode_step(cfg, params, cache, token, pos, ctx)
-
-    p_sds = abstract_param_tree(cfg, mesh, rules)
-    specs = api.input_specs(cfg, shape)
-    cache_sds = _with_shardings(specs["cache"],
-                                api.cache_axes(cfg), mesh, rules)
-    token_sds = _sds((shape.global_batch, 1), jnp.int32, mesh,
-                     spec_for_axes(mesh, rules, (shape.global_batch, 1),
-                                   ("batch", None)))
-    pos_sds = _sds((), jnp.int32, mesh, P())
-    return serve_step, (p_sds, cache_sds, token_sds, pos_sds)
-
-
-def build_step(cfg: ModelConfig, mesh: Mesh, shape: ShapeConfig, **kw):
-    if shape.kind == "train":
-        return build_train_step(cfg, mesh, shape, **kw)
-    if shape.kind == "prefill":
-        return build_prefill_step(cfg, mesh, shape, **kw)
-    return build_decode_step(cfg, mesh, shape, **kw)

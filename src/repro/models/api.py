@@ -1,6 +1,7 @@
-"""Unified model API: dispatches lm.py vs whisper.py by family, and builds
-the abstract ``input_specs`` (ShapeDtypeStructs) every dry-run cell lowers
-against — the same pattern production launchers use (no allocation)."""
+"""Unified model API: dispatches lm.py vs whisper.py by family, owns the
+decode cache's layout (``cache_sds``, ``cache_axes``, ``grow_cache``), and
+builds the abstract ``input_specs`` (ShapeDtypeStructs) a step lowers against
+without allocating."""
 from __future__ import annotations
 
 from typing import Any, Dict, Optional, Tuple
@@ -66,23 +67,34 @@ def cache_sds(cfg: ModelConfig, batch: int, max_len: int):
     return lm.cache_sds(cfg, batch, max_len)
 
 
-def cache_axes(cfg: ModelConfig, batch: int = 1, max_len: int = 8):
+def cache_axes(cfg: ModelConfig):
     """Logical-axis tree matching cache_sds structure."""
     if is_encdec(cfg):
         return whisper.cache_axes_tree()
-    _, _, axes = lm.cache_spec(cfg, batch, max_len)
+    _, _, axes = lm.cache_spec(cfg, 1, 1)
     return axes
 
 
-def make_cache(cfg: ModelConfig, batch: int, max_len: int):
-    if is_encdec(cfg):
-        sds = whisper.cache_sds(cfg, batch, max_len)
-        return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), sds)
-    return lm.make_cache(cfg, batch, max_len)
+def grow_cache(cfg: ModelConfig, cache, max_len: int):
+    """Pad a prefill cache out to ``max_len`` decode slots: every leaf along
+    the axis ``cache_axes`` labels ``cache_seq``, and no other leaf or axis
+    (recurrent state and whisper's cross k/v pass unchanged)."""
+    def pad(a, axes):
+        if "cache_seq" not in axes:
+            return a
+        seq = axes.index("cache_seq")
+        extra = max_len - a.shape[seq]
+        if extra <= 0:
+            return a
+        widths = [(0, 0)] * a.ndim
+        widths[seq] = (0, extra)
+        return jnp.pad(a, widths)
+
+    return jax.tree.map(pad, cache, cache_axes(cfg))
 
 
 # ---------------------------------------------------------------------------
-# Abstract input specs per (arch x shape) — the dry-run contract.
+# Abstract input specs per (arch x shape).
 # ---------------------------------------------------------------------------
 
 def input_specs(cfg: ModelConfig, shape: ShapeConfig) -> Dict[str, Any]:

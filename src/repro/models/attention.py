@@ -70,51 +70,27 @@ def _mla_defs(cfg: ModelConfig) -> Params:
     }
 
 
-def init_cache(cfg: ModelConfig, batch: int, max_len: int,
-               stack_dims: Tuple[int, ...] = ()) -> Params:
-    """Abstract per-layer-slot cache entry (use jnp.zeros / SDS externally)."""
+def init_cache(cfg: ModelConfig, batch: int, max_len: int) -> Params:
+    """Per-layer cache entry shapes (the layer stack's axis added by lm)."""
     hd, nkv = cfg.resolved_head_dim, cfg.num_kv_heads
     if cfg.attention_type == "mla":
         m = cfg.mla
-        return {"ckv": stack_dims + (batch, max_len, m.kv_lora_rank),
-                "kpe": stack_dims + (batch, max_len, m.qk_rope_head_dim)}
-    out = {"k": stack_dims + (batch, max_len, nkv, hd),
-           "v": stack_dims + (batch, max_len, nkv, hd)}
-    if cfg.kv_cache_dtype == "int8":
-        out["k_scale"] = stack_dims + (batch, max_len, nkv)
-        out["v_scale"] = stack_dims + (batch, max_len, nkv)
-    return out
+        return {"ckv": (batch, max_len, m.kv_lora_rank),
+                "kpe": (batch, max_len, m.qk_rope_head_dim)}
+    return {"k": (batch, max_len, nkv, hd), "v": (batch, max_len, nkv, hd)}
 
 
-def cache_axes(cfg: ModelConfig, stacked: bool = True) -> Params:
-    pre = ("layers",) if stacked else ()
+def cache_axes(cfg: ModelConfig) -> Params:
+    """Logical axes of the stacked cache entries ``init_cache`` shapes."""
     if cfg.attention_type == "mla":
-        return {"ckv": pre + ("cache_batch", "cache_seq", None),
-                "kpe": pre + ("cache_batch", "cache_seq", None)}
-    ax = pre + ("cache_batch", "cache_seq", "cache_heads", None)
-    out = {"k": ax, "v": ax}
-    if cfg.kv_cache_dtype == "int8":
-        sax = pre + ("cache_batch", "cache_seq", "cache_heads")
-        out["k_scale"] = sax
-        out["v_scale"] = sax
-    return out
-
-
-def _quantize_kv(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    """Per-(token, head) symmetric int8 over the head_dim axis."""
-    amax = jnp.max(jnp.abs(x.astype(jnp.float32)), axis=-1)
-    scale = jnp.where(amax > 0, amax / 127.0, 1.0)
-    q = jnp.clip(jnp.round(x.astype(jnp.float32) / scale[..., None]),
-                 -127, 127).astype(jnp.int8)
-    return q, scale
-
-
-def _dequantize_kv(q: jax.Array, scale: jax.Array, dt) -> jax.Array:
-    return (q.astype(jnp.float32) * scale[..., None].astype(jnp.float32)).astype(dt)
+        return {"ckv": ("layers", "cache_batch", "cache_seq", None),
+                "kpe": ("layers", "cache_batch", "cache_seq", None)}
+    ax = ("layers", "cache_batch", "cache_seq", "cache_heads", None)
+    return {"k": ax, "v": ax}
 
 
 # ---------------------------------------------------------------------------
-# Core attention math (XLA path; pallas kernels dispatched from here)
+# Core attention math
 # ---------------------------------------------------------------------------
 
 def _sdpa(cfg: ModelConfig, q: jax.Array, k: jax.Array, v: jax.Array,
@@ -130,22 +106,6 @@ def _sdpa(cfg: ModelConfig, q: jax.Array, k: jax.Array, v: jax.Array,
     out = jnp.einsum("bhgqk,bkhd->bqhgd", w, v)
     dv = v.shape[-1]  # may differ from hd (MLA)
     return out.reshape(B, Sq, Hq * dv)
-
-
-def _maybe_pallas_attention(cfg: ModelConfig, q, k, v, mode: str,
-                            pos: Optional[jax.Array]) -> Optional[jax.Array]:
-    if cfg.attention_impl == "xla":
-        return None
-    interpret = cfg.attention_impl == "pallas_interpret"
-    from repro.kernels import ops as kops
-    B, Sq, Hq, hd = q.shape
-    if mode in ("train", "prefill") and Sq > 1:
-        y = kops.flash_attention(q, k, v, True, interpret)
-        return y.reshape(B, Sq, Hq * hd)
-    if mode == "decode":
-        y = kops.decode_attention(q, k, v, kv_len=pos + 1, interpret=interpret)
-        return y.reshape(B, Sq, Hq * hd)
-    return None
 
 
 # ---------------------------------------------------------------------------
@@ -196,49 +156,24 @@ def gqa_apply(cfg: ModelConfig, p: Params, x: jax.Array, *,
 
     if mode == "decode" and kv_override is None:
         # insert new k/v at pos, attend over cache[0..pos]
-        if cfg.kv_cache_dtype == "int8":
-            # §Perf (decode): int8 cache halves the dominant HBM stream;
-            # dequant fuses after the (int8) loads on TPU.
-            qk, sk = _quantize_kv(k)
-            qv, sv = _quantize_kv(v)
-            ck = jax.lax.dynamic_update_slice(cache["k"], qk, (0, pos, 0, 0))
-            cv = jax.lax.dynamic_update_slice(cache["v"], qv, (0, pos, 0, 0))
-            csk = jax.lax.dynamic_update_slice(
-                cache["k_scale"], sk.astype(cache["k_scale"].dtype), (0, pos, 0))
-            csv = jax.lax.dynamic_update_slice(
-                cache["v_scale"], sv.astype(cache["v_scale"].dtype), (0, pos, 0))
-            new_cache = {"k": ck, "v": cv, "k_scale": csk, "v_scale": csv}
-            ck_ = _dequantize_kv(ck, csk, dt)
-            cv_ = _dequantize_kv(cv, csv, dt)
-        else:
-            ck = jax.lax.dynamic_update_slice(cache["k"], k.astype(cache["k"].dtype),
-                                              (0, pos, 0, 0))
-            cv = jax.lax.dynamic_update_slice(cache["v"], v.astype(cache["v"].dtype),
-                                              (0, pos, 0, 0))
-            new_cache = {"k": ck, "v": cv}
-            ck_, cv_ = ck.astype(dt), cv.astype(dt)
-        y = _maybe_pallas_attention(cfg, q, ck_, cv_, "decode", pos)
-        if y is None:
-            S_max = ck.shape[1]
-            valid = (jnp.arange(S_max) <= pos)[None, None, :]  # [1,1,S_max]
-            y = _sdpa(cfg, q, ck_, cv_, valid, ctx, scale)
+        ck = jax.lax.dynamic_update_slice(cache["k"], k.astype(cache["k"].dtype),
+                                          (0, pos, 0, 0))
+        cv = jax.lax.dynamic_update_slice(cache["v"], v.astype(cache["v"].dtype),
+                                          (0, pos, 0, 0))
+        new_cache = {"k": ck, "v": cv}
+        S_max = ck.shape[1]
+        valid = (jnp.arange(S_max) <= pos)[None, None, :]  # [1,1,S_max]
+        y = _sdpa(cfg, q, ck.astype(dt), cv.astype(dt), valid, ctx, scale)
     else:
         if mode == "prefill" and kv_override is None:
-            if cfg.kv_cache_dtype == "int8":
-                qk, sk = _quantize_kv(k)
-                qv, sv = _quantize_kv(v)
-                new_cache = {"k": qk, "v": qv, "k_scale": sk, "v_scale": sv}
-            else:
-                new_cache = {"k": k, "v": v}
+            new_cache = {"k": k, "v": v}
         if kv_override is not None:  # cross-attention: full visibility
             mask = jnp.ones((1, S, k.shape[1]), bool)
             y = _sdpa(cfg, q, k.astype(dt), v.astype(dt), mask, ctx, scale)
         else:
-            y = _maybe_pallas_attention(cfg, q, k, v, mode, pos) if causal else None
-            if y is None:
-                mask = (jnp.tril(jnp.ones((S, S), bool)) if causal
-                        else jnp.ones((S, S), bool))[None]
-                y = _sdpa(cfg, q, k, v, mask, ctx, scale)
+            mask = (jnp.tril(jnp.ones((S, S), bool)) if causal
+                    else jnp.ones((S, S), bool))[None]
+            y = _sdpa(cfg, q, k, v, mask, ctx, scale)
 
     y = ctx.constrain(y, ("batch", "seq", "act_heads"))
     return y @ p["wo"].astype(dt), new_cache

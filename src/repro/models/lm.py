@@ -103,9 +103,7 @@ def _pos_cache_shapes(cfg: ModelConfig, mixer: str, batch: int, max_len: int) ->
 
 
 def _cache_dtype(cfg: ModelConfig, mixer: str, name: str) -> jnp.dtype:
-    if mixer == "attn" and name in ("k", "v"):
-        return jnp.dtype(jnp.int8 if cfg.kv_cache_dtype == "int8" else cfg.dtype)
-    if mixer == "attn" and name in ("ckv", "kpe"):
+    if mixer == "attn":
         return jnp.dtype(cfg.dtype)
     if mixer == "mamba" and name == "conv":
         return jnp.dtype(cfg.dtype)
@@ -126,18 +124,12 @@ def cache_spec(cfg: ModelConfig, batch: int, max_len: int):
         shapes[key] = {k: (n,) + tuple(v) for k, v in sh.items()}
         dtypes[key] = {k: _cache_dtype(cfg, mixer, k) for k in sh}
         if mixer == "attn":
-            ax = attn.cache_axes(cfg, stacked=True)
+            ax = attn.cache_axes(cfg)
         else:
             ax = {k: ("layers", "cache_batch") + (None,) * (len(v) - 1)
                   for k, v in sh.items()}
         axes[key] = ax
     return shapes, dtypes, axes
-
-
-def make_cache(cfg: ModelConfig, batch: int, max_len: int) -> Params:
-    shapes, dtypes, _ = cache_spec(cfg, batch, max_len)
-    return jax.tree.map(lambda s, d: jnp.zeros(s, d), shapes, dtypes,
-                        is_leaf=lambda x: isinstance(x, tuple))
 
 
 def cache_sds(cfg: ModelConfig, batch: int, max_len: int) -> Params:
@@ -257,31 +249,13 @@ def forward(cfg: ModelConfig, params: Params, tokens: jax.Array, *,
         return x, (new_caches, aux_sum if aux_sum is not None else {})
 
     xs_cache = cache if has_cache else jax.tree.map(lambda _: None, params["blocks"])
-    if cfg.scan_layers:
-        body = period_body
-        if mode == "train" and cfg.remat != "none":
-            policy = (jax.checkpoint_policies.nothing_saveable if cfg.remat == "full"
-                      else jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims)
-            body = jax.checkpoint(period_body, policy=policy)
-        layers = jnp.arange(cfg.num_periods) if stacks is not None else None
-        x, (new_cache, auxs) = jax.lax.scan(body, x, (params["blocks"], xs_cache, layers))
-    else:
-        body = period_body
-        if mode == "train" and cfg.remat != "none":
-            policy = (jax.checkpoint_policies.nothing_saveable if cfg.remat == "full"
-                      else jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims)
-            body = jax.checkpoint(period_body, policy=policy)
-        new_cache, auxs = {}, []
-        for li in range(cfg.num_periods):
-            sl = jax.tree.map(lambda a: a[li], params["blocks"])
-            cl = jax.tree.map(lambda a: a[li], cache) if has_cache else None
-            x, (nc, aux) = body(x, (sl, cl, li if stacks is not None else None))
-            if want_cache:
-                new_cache[li] = nc
-            auxs.append(aux)
-        if want_cache:
-            new_cache = jax.tree.map(lambda *xs_: jnp.stack(xs_), *new_cache.values())
-        auxs = jax.tree.map(lambda *xs_: jnp.stack(xs_), *auxs) if auxs and auxs[0] else {}
+    body = period_body
+    if mode == "train" and cfg.remat != "none":
+        policy = (jax.checkpoint_policies.nothing_saveable if cfg.remat == "full"
+                  else jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims)
+        body = jax.checkpoint(period_body, policy=policy)
+    layers = jnp.arange(cfg.num_periods) if stacks is not None else None
+    x, (new_cache, auxs) = jax.lax.scan(body, x, (params["blocks"], xs_cache, layers))
 
     if want_cache and lead_cache is not None:
         new_cache = {**new_cache, LEAD: lead_cache}

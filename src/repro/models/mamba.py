@@ -145,8 +145,7 @@ def mamba_apply(cfg: ModelConfig, p: Params, x: jax.Array, *,
                 return h_last, yc.astype(xc.dtype)
             A_bar, Bx, C_full = _ssm_params(cfg, p, xc)
             xs = (rs(A_bar), rs(Bx), rs(C_full))
-        h_last, ys = jax.lax.scan(step, h0, xs,
-                                  unroll=True if cfg.unroll_scans else 1)
+        h_last, ys = jax.lax.scan(step, h0, xs)
         y = jnp.moveaxis(ys, 0, 1).reshape(B, S, d_in)
         new_cache = None
         if mode == "prefill":

@@ -72,7 +72,7 @@ def init_params(defs: PyTree, key: jax.Array, param_dtype: str = "float32") -> P
 
 
 def abstract_params(defs: PyTree, param_dtype: str = "float32") -> PyTree:
-    """ShapeDtypeStructs for the def tree (no allocation — dry-run path)."""
+    """ShapeDtypeStructs for the def tree (no allocation)."""
     def f(d: ParamDef) -> jax.ShapeDtypeStruct:
         return jax.ShapeDtypeStruct(d.shape, jnp.dtype(d.dtype or param_dtype))
     return jax.tree.map(f, defs, is_leaf=lambda x: isinstance(x, ParamDef))

@@ -77,8 +77,7 @@ def encode(cfg: ModelConfig, params: Params, frames: jax.Array,
         x = x + L.apply_mlp(cfg, p["mlp"], h, ctx)
         return ctx.constrain(x, ("batch", "seq", None)), {}
 
-    x, _ = jax.lax.scan(body, x, params["enc_blocks"],
-                        unroll=True if not cfg.scan_layers else 1)
+    x, _ = jax.lax.scan(body, x, params["enc_blocks"])
     return L.apply_norm(cfg, params["enc_norm"], x)
 
 
@@ -130,8 +129,7 @@ def _decode_stack(cfg: ModelConfig, params: Params, x: jax.Array, *, mode: str,
         jax.tree.map(lambda _: None, params["dec_blocks"])
     cc = cross_cache if cross_cache is not None else \
         jax.tree.map(lambda _: None, params["dec_blocks"])
-    x, ys = jax.lax.scan(body, x, (params["dec_blocks"], sc, cc),
-                         unroll=True if not cfg.scan_layers else 1)
+    x, ys = jax.lax.scan(body, x, (params["dec_blocks"], sc, cc))
     return x, ys
 
 

@@ -155,8 +155,7 @@ def mlstm_apply(cfg: ModelConfig, p: Params, x: jax.Array, *, mode: str,
         carry0 = (jnp.zeros((B, H, dk, dk), jnp.float32),
                   jnp.zeros((B, H, dk), jnp.float32),
                   jnp.full((B, H), MIN_LOG, jnp.float32))
-        carry, hs = jax.lax.scan(step, carry0, (rs(q), rs(k), rs(v), rs(i_pre), rs(log_f)),
-                                 unroll=True if cfg.unroll_scans else 1)
+        carry, hs = jax.lax.scan(step, carry0, (rs(q), rs(k), rs(v), rs(i_pre), rs(log_f)))
         h = jnp.moveaxis(hs, 0, 1).reshape(B, S, H, dk)
         new_cache = ({"C": carry[0], "n": carry[1], "m": carry[2]}
                      if mode == "prefill" else None)

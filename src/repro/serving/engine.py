@@ -206,17 +206,6 @@ class ServeEngine:
             self.stats.moe_dropped += dropped
 
     def _grow_cache(self, cache, plen: int):
-        """Pad prefill cache out to max_len decode slots."""
-        extra = self.max_len - plen
-        if extra <= 0:
-            return cache
-
-        def pad(path, a):
-            name = str(getattr(path[-1], "key", ""))
-            if name in ("k", "v") and a.ndim == 5:
-                return jnp.pad(a, ((0, 0), (0, 0), (0, extra), (0, 0), (0, 0)))
-            if name in ("ckv", "kpe") and a.ndim == 4:
-                return jnp.pad(a, ((0, 0), (0, 0), (0, extra), (0, 0)))
-            return a
-
-        return jax.tree_util.tree_map_with_path(pad, cache)
+        """Pad a cache prefilled on ``plen`` tokens out to max_len decode
+        slots (``api.grow_cache``)."""
+        return api.grow_cache(self.cfg, cache, self.max_len)

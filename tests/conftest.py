@@ -1,8 +1,8 @@
 import os
 import sys
 
-# Tests must see exactly ONE device (the dry-run sets its own XLA_FLAGS in a
-# separate process); fail fast if something polluted the env.
+# Tests must see exactly ONE host device; fail fast if something polluted the
+# env.
 assert "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", "")
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))

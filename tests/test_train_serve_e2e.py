@@ -57,6 +57,22 @@ def test_serving_engine_batch():
     assert eng.step_batch() == []           # queue drained
 
 
+@pytest.mark.parametrize("arch", ["jamba-v0.1-52b", "xlstm-125m", "minicpm3-4b"])
+def test_serving_engine_cache_has_the_decode_layout(arch):
+    """The engine grows a hybrid, recurrent or latent prefill cache inside
+    its prefill program to the decode program's ``api.cache_sds``."""
+    cfg = get_config(arch, smoke=True)
+    params = api.init(cfg, jax.random.PRNGKey(0))
+    eng = ServeEngine(cfg, params, max_batch=2, max_len=12)
+    eng.submit(GenRequest("r1", [1, 2, 3, 4, 5, 6], max_new_tokens=3))
+    eng.submit(GenRequest("r2", [7, 8, 9, 10, 11, 12], max_new_tokens=3))
+    assert [len(r.result) for r in eng.step_batch()] == [3, 3]
+    want = api.cache_sds(cfg, 2, 12)
+    assert jax.tree.map(lambda a: (a.shape, a.dtype), eng.last_state.cache) == \
+        jax.tree.map(lambda s: (s.shape, s.dtype), want)
+    assert eng.last_state.pos == 8
+
+
 def test_serving_engine_greedy_deterministic():
     cfg = get_config("qwen3-4b", smoke=True)
     params = api.init(cfg, jax.random.PRNGKey(0))
